@@ -18,6 +18,9 @@ sys.path.insert(0, str(pathlib.Path(__file__).resolve().parent.parent))
 
 
 def main() -> None:
+    from repro import compile_cache
+
+    compile_cache.enable()
     from benchmarks import bench_kernels, bench_pca, bench_rsvd, bench_spectra
     from benchmarks import bench_sumc, roofline_report
 

@@ -10,9 +10,11 @@ Run:  PYTHONPATH=src python examples/quickstart.py
 import jax.numpy as jnp
 import numpy as np
 
-from repro import linalg
+from repro import compile_cache, linalg
 from repro.core import RSVDConfig, truncation_error
 from repro.core.spectra import make_test_matrix
+
+compile_cache.enable()
 
 # A 1024 x 512 matrix with the paper's 'fast decay' spectrum (sigma_i = 1/i^2)
 A, sigma = make_test_matrix(1024, 512, "fast", seed=0)

@@ -52,7 +52,7 @@ VIEW_PRIMS = {"transpose", "reshape", "squeeze", "expand_dims", "rev"}
 STAGING_PRIMS = {"pad"}
 #: call-like primitives whose sub-jaxpr invars match the eqn invars
 #: positionally, letting tags flow through.
-CALL_PRIMS = {"pjit", "custom_jvp_call", "custom_vjp_call", "closed_call",
+CALL_PRIMS = {"jit", "custom_jvp_call", "custom_vjp_call", "closed_call",
               "remat2", "checkpoint", "shard_map", "custom_vjp_call_jaxpr"}
 
 
@@ -92,7 +92,7 @@ class JaxprFacts:
 
 def _open_jaxpr(obj):
     """Duck-typed: ClosedJaxpr -> .jaxpr, open Jaxpr -> itself, else None.
-    (shard_map carries an *open* jaxpr param; pjit a ClosedJaxpr.)"""
+    (shard_map carries an *open* jaxpr param; jit a ClosedJaxpr.)"""
     if hasattr(obj, "jaxpr") and hasattr(obj, "consts"):
         return obj.jaxpr
     if hasattr(obj, "eqns") and hasattr(obj, "invars"):

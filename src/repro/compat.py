@@ -1,11 +1,6 @@
-"""Version-compatibility shims for the jax API surface this repo uses.
-
-The codebase is written against the modern jax API (``jax.shard_map`` with
-``axis_names=`` / ``check_vma=``, ``jax.sharding.get_abstract_mesh``,
-``jax.enable_x64``).  Older jax releases (e.g. the 0.4.x line pinned in some
-containers) expose the same functionality under ``jax.experimental`` with
-different keyword names.  Every call site imports from here so the rest of
-the code reads as modern jax and upgrades are a one-file change.
+"""The few jax entry points this repo wraps, spelled for the installed jax
+(0.9).  Every call site imports from here, so a later jax upgrade is a
+one-file change.
 """
 from __future__ import annotations
 
@@ -13,8 +8,6 @@ import contextlib
 from typing import Iterable, Optional
 
 import jax
-
-_HAS_TOP_LEVEL_SHARD_MAP = hasattr(jax, "shard_map")
 
 
 def shard_map(
@@ -25,58 +18,29 @@ def shard_map(
     axis_names: Optional[Iterable[str]] = None,
     check_vma: Optional[bool] = None,
 ):
-    """``jax.shard_map`` on new jax; ``jax.experimental.shard_map`` on old.
-
-    ``axis_names`` is the set of MANUAL axes (modern spelling); on old jax it
-    is translated to the complementary ``auto=`` frozenset.  ``check_vma``
-    maps to old ``check_rep``.
-    """
-    check = True if check_vma is None else check_vma
-    if _HAS_TOP_LEVEL_SHARD_MAP:
-        kwargs = dict(mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=check)
-        if axis_names is not None:
-            kwargs["axis_names"] = set(axis_names)
-        return jax.shard_map(f, **kwargs)
-    from jax.experimental.shard_map import shard_map as _sm
-
-    auto = frozenset()
+    """``jax.shard_map``; ``axis_names`` is the set of MANUAL axes (None:
+    all of the mesh's axes), ``check_vma=None`` keeps jax's default."""
+    kwargs = dict(mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+                  check_vma=True if check_vma is None else check_vma)
     if axis_names is not None:
-        auto = frozenset(mesh.axis_names) - frozenset(axis_names)
-    return _sm(
-        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-        check_rep=check, auto=auto,
-    )
+        kwargs["axis_names"] = set(axis_names)
+    return jax.shard_map(f, **kwargs)
 
 
 @contextlib.contextmanager
 def enable_x64():
-    """``with jax.enable_x64(True)`` / ``jax.experimental.enable_x64()``."""
-    if hasattr(jax, "enable_x64"):
-        with jax.enable_x64(True):
-            yield
-        return
-    from jax.experimental import enable_x64 as _e64
-
-    with _e64():
+    """``with jax.enable_x64(True)``."""
+    with jax.enable_x64(True):
         yield
 
 
 def manual_axis_names() -> set:
     """Mesh axis names currently bound as Manual (inside a shard_map body).
 
-    with_sharding_constraint specs must not mention these.  New jax exposes
-    them on the abstract mesh; old jax binds them in the axis environment.
-    """
-    am = getattr(jax.sharding, "get_abstract_mesh", lambda: None)()
-    if am is not None and getattr(am, "axis_types", None):
-        return {
-            n
-            for n, t in zip(am.axis_names, am.axis_types)
-            if t == jax.sharding.AxisType.Manual
-        }
-    try:
-        from jax._src import core as _core
-
-        return set(_core.unsafe_get_axis_names())
-    except Exception:  # pragma: no cover - last-resort fallback
-        return set()
+    with_sharding_constraint specs must not mention these."""
+    am = jax.sharding.get_abstract_mesh()
+    return {
+        n
+        for n, t in zip(am.axis_names, am.axis_types)
+        if t == jax.sharding.AxisType.Manual
+    }

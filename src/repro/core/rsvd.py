@@ -151,18 +151,19 @@ def _use_fused_power(
     """The one-pass power path needs fp32-accumulating kernels (not the f64
     faithful setting), a CholeskyQR-family range finder (the Y-side
     re-orthonormalization is expressed through Gram + TRSM), and a working
-    set — the A strip plus the n x s accumulators — that fits real-TPU
-    VMEM (interpret mode has no limit, but the config path must not select
-    a kernel that cannot compile on hardware; beyond the budget the
-    blocked/streaming and distributed paths are the intended scale-out).
-    The execution planner (repro/linalg/planner.py) evaluates the same gate
-    at plan time, parameterized by its Budget — `vmem_budget` keeps the two
-    in lockstep."""
+    set — the A strip plus the n x s accumulators — that fits the VMEM
+    limit the kernels compile with, `hw.VMEM_BYTES` (interpret mode has no
+    limit, but the config path must not select a kernel that cannot
+    compile on hardware; beyond the budget the blocked/streaming and
+    distributed paths are the intended scale-out).  The execution planner
+    (repro/linalg/planner.py) evaluates the same gate at plan time,
+    parameterized by its Budget — `vmem_budget` keeps the two in lockstep."""
     from repro.kernels.ops import _block, _select_blocks
-    from repro.kernels.power_step import VMEM_BUDGET_BYTES, fused_power_vmem_bytes
+    from repro.kernels.power_step import fused_power_vmem_bytes
+    from repro.roofline import hw
 
     if vmem_budget is None:
-        vmem_budget = VMEM_BUDGET_BYTES
+        vmem_budget = hw.VMEM_BYTES
     m, n = A.shape
     # Model the kernel's ACTUAL footprint: the bm the wrapper will select
     # (autotune cache included) and the padded dims it will allocate.
@@ -177,7 +178,8 @@ def _use_fused_power(
         cfg.fused_power
         and A.dtype != jnp.float64
         and (cfg.power_scheme == "plain" or cfg.qr_method == "cqr2")
-        and fused_power_vmem_bytes(n_pad, s_pad, bm=bm) <= vmem_budget
+        and fused_power_vmem_bytes(n_pad, s_pad, bm=bm,
+                                   dtype_bytes=A.dtype.itemsize) <= vmem_budget
     )
 
 

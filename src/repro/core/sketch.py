@@ -57,7 +57,8 @@ def hash_u32(idx: jax.Array, seed: jax.Array | int) -> jax.Array:
 def _u32_to_unit(bits: jax.Array) -> jax.Array:
     """uint32 -> float32 uniform in (0, 1]  (never 0, so log() is safe)."""
     # Take the top 24 bits -> [0, 2^24), then (x + 1) / 2^24 in (0, 1].
-    return (bits >> np.uint32(8)).astype(jnp.float32) * np.float32(
+    # Cast via int32 (exact below 2^24), as the Pallas kernel must.
+    return (bits >> np.uint32(8)).astype(jnp.int32).astype(jnp.float32) * np.float32(
         1.0 / 16777216.0
     ) + np.float32(1.0 / 16777216.0)
 

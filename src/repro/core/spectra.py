@@ -48,5 +48,7 @@ def make_test_matrix(
     sig = spectrum(r, kind, beta, dtype)
     U = random_orthogonal(m, r, seed, dtype)
     V = random_orthogonal(n, r, seed + 1, dtype)
-    A = (U * sig[None, :]) @ V.T
+    # HIGHEST: on a TPU the default f32 matmul is one bf16 pass, which would
+    # leave A's spectrum off `sig` by ~1e-3 relative
+    A = jnp.matmul(U * sig[None, :], V.T, precision=jax.lax.Precision.HIGHEST)
     return A, sig

@@ -1,6 +1,7 @@
 """JIT'd public wrappers for the Pallas kernels: padding, dtype policy, and
-interpret-mode selection (CPU container validates in interpret mode; on real
-TPU the same call sites compile the kernels natively).
+interpret-mode selection (on the CPU backend the kernels run in Pallas
+interpret mode; on a TPU the same call sites compile them natively; any
+other backend is refused).
 """
 from __future__ import annotations
 
@@ -25,8 +26,17 @@ def _on_tpu() -> bool:
 
 
 def _interpret() -> bool:
-    # Kernels execute in interpret mode everywhere except real TPUs.
-    return not _on_tpu()
+    """Interpret mode on the CPU backend only.  On a TPU the kernels compile
+    natively and a kernel the compiler refuses raises; any other backend
+    raises here rather than silently interpreting."""
+    backend = jax.default_backend()
+    if backend == "tpu":
+        return False
+    if backend == "cpu":
+        return True
+    raise RuntimeError(
+        f"Pallas kernels run natively on a TPU or in interpret mode on the "
+        f"CPU; backend {backend!r} is neither")
 
 
 def _backend_name() -> str:

@@ -24,7 +24,10 @@ HBM bytes per power step (fp32, the DESIGN.md §2 table):
 
 VMEM working set per grid step: the (bm x n) A strip + X + the Z
 accumulator (both n x s).  X and Z have constant index maps, so they are
-fetched/flushed once for the whole grid, not per strip.
+fetched/flushed once for the whole grid, not per strip.  The kernels ask
+the compiler for the core's whole VMEM (`hw.VMEM_BYTES`), and the fusion
+gate (`fused_power_vmem_bytes`) checks the working set against that same
+number, so a plan that claims fusion is one the chip's compiler accepts.
 """
 from __future__ import annotations
 
@@ -35,22 +38,32 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# Conservative per-core VMEM budget for the working-set guard below (real
-# TPUs have ~16 MB; leave headroom for double buffering and the Y block).
-VMEM_BUDGET_BYTES = 12 * 1024 * 1024
+from repro.roofline import hw
+
+_COMPILER_PARAMS = pltpu.CompilerParams(vmem_limit_bytes=hw.VMEM_BYTES)
+
+#: rows of Ω generated per step of the sketch kernel's fill loop — bounds
+#: the RNG temporaries to a (chunk x s) tile instead of the whole (n x s) Ω
+_OMEGA_CHUNK = 128
 
 
 def fused_power_vmem_bytes(n: int, s: int, bm: int = 128, dtype_bytes: int = 4) -> int:
-    """Working-set estimate of one grid step: the (bm x n) A strip, the
-    (n x s) X input block, the (n x s) Z accumulator + its output block,
-    and the fp32 Y/G scratch.  Callers (core/rsvd.py) fall back to the
-    unfused path when this exceeds VMEM_BUDGET_BYTES — interpret mode has
-    no such limit, but the guard keeps the config-driven path honest about
-    what compiles on real hardware; beyond it, the blocked/streaming or
-    distributed paths are the intended scale-out."""
-    strip = bm * n * dtype_bytes
-    ns = n * s
-    return strip + 3 * ns * 4 + bm * s * 4 + s * s * 4
+    """VMEM the strip kernels allocate at padded (n, s), counted high.
+
+    Every pipelined block is double-buffered: the (bm x n) A strip, the
+    (bm x s) Y block, the (n x s) X input (power_step) and Z output, and
+    the (s x s) G output.  Scratch: the (n x s) Ω or Z accumulator pair and
+    the G accumulator.  Temporaries: the f32 strip and its transpose, the
+    (n x s) AᵀY product, the Y tile, and the RNG's (chunk x s) tiles.
+    Callers (core/rsvd.py, the planner) fuse only when this fits
+    `hw.VMEM_BYTES`, the limit the kernels compile with."""
+    f32 = 4
+    ns, ss = n * s, s * s
+    blocks = 2 * (bm * n * dtype_bytes + bm * s * dtype_bytes
+                  + 2 * ns * dtype_bytes + ss * f32)
+    scratch = 2 * ns * f32 + ss * f32
+    temps = 2 * bm * n * f32 + ns * f32 + bm * s * f32 + 8 * _OMEGA_CHUNK * s * f32
+    return blocks + scratch + temps
 
 
 def _power_step_kernel(a_ref, x_ref, y_ref, z_ref, zacc_ref, *, ni):
@@ -108,9 +121,17 @@ def _sketch_power_kernel(
 
     @pl.when(i == 0)
     def _init():
-        omega_ref[...] = _omega_tile(
-            jnp.uint32(0), jnp.uint32(0), n_p, sp, s, seed_ref[0, 0], kind
-        )
+        chunk = min(_OMEGA_CHUNK, n_p)
+
+        def fill(c, carry):
+            r0 = pl.multiple_of(c * chunk, chunk)
+            omega_ref[pl.ds(r0, chunk), :] = _omega_tile(
+                r0.astype(jnp.uint32), jnp.uint32(0), chunk, sp, s,
+                seed_ref[0, 0], kind,
+            )
+            return carry
+
+        jax.lax.fori_loop(0, n_p // chunk, fill, 0)
         zacc_ref[...] = jnp.zeros_like(zacc_ref)
         gacc_ref[...] = jnp.zeros_like(gacc_ref)
 
@@ -149,6 +170,7 @@ def sketch_power_padded(
     kernel = functools.partial(
         _sketch_power_kernel, ni=ni, s=s, sp=s_padded, kind=kind
     )
+    assert n % min(_OMEGA_CHUNK, n) == 0
     sd = jnp.asarray(seed, jnp.uint32).reshape(1, 1)
     return pl.pallas_call(
         kernel,
@@ -172,6 +194,7 @@ def sketch_power_padded(
             pltpu.VMEM((n, s_padded), jnp.float32),
             pltpu.VMEM((s_padded, s_padded), jnp.float32),
         ],
+        compiler_params=_COMPILER_PARAMS,
         interpret=interpret,
     )(sd, a)
 
@@ -234,5 +257,6 @@ def power_step_padded(
         out_specs=out_specs,
         out_shape=out_shape,
         scratch_shapes=scratch,
+        compiler_params=_COMPILER_PARAMS,
         interpret=interpret,
     )(a, x)

@@ -43,7 +43,9 @@ def _hash_u32(idx, seed):
 
 
 def _u32_to_unit(bits):
-    return (bits >> np.uint32(8)).astype(jnp.float32) * np.float32(
+    # via int32: the value is < 2^24, so the result is exact, and Mosaic
+    # has no uint32 -> float32 cast
+    return (bits >> np.uint32(8)).astype(jnp.int32).astype(jnp.float32) * np.float32(
         1.0 / 16777216.0
     ) + np.float32(1.0 / 16777216.0)
 

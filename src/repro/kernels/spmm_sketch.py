@@ -74,7 +74,7 @@ def pack_block_ell(bcoo, bm: int, bk: int, max_fill: float | None = None):
 
 def _spmm_sketch_kernel(cols_ref, seed_ref, data_ref, o_ref, acc_ref,
                         *, nt, bk, sp, s, kind):
-    t = pl.program_id(1)
+    i, t = pl.program_id(0), pl.program_id(1)
 
     @pl.when(t == 0)
     def _init():
@@ -82,8 +82,8 @@ def _spmm_sketch_kernel(cols_ref, seed_ref, data_ref, o_ref, acc_ref,
 
     # this tile holds A columns [c*bk, (c+1)*bk) -> Omega rows of the same
     # range; generate that slab in VMEM, keyed by the prefetched tile id
-    row0 = cols_ref[0, 0].astype(jnp.uint32) * np.uint32(bk)
-    omega = _omega_tile(row0, jnp.uint32(0), bk, sp, s, seed_ref[0, 0], kind)
+    row0 = cols_ref[i * nt + t].astype(jnp.uint32) * np.uint32(bk)
+    omega = _omega_tile(row0, jnp.uint32(0), bk, sp, s, seed_ref[0], kind)
     acc_ref[...] += jnp.dot(
         data_ref[0, 0].astype(jnp.float32), omega,
         preferred_element_type=jnp.float32,
@@ -112,25 +112,26 @@ def spmm_sketch_padded(
     `s` is the LOGICAL sketch width (the RNG flat index uses it, so results
     are independent of padding); columns >= s of the padded output are
     garbage the caller slices off.  Zero-padded tiles multiply a valid Omega
-    slab by zeros, so they are numerically inert.  ``seed`` is a traced SMEM
-    scalar — seed sweeps share one compiled program, as in the dense kernels.
+    slab by zeros, so they are numerically inert.  The tile ids (flattened)
+    and ``seed`` are scalar-prefetched into SMEM — the seed is traced, so
+    seed sweeps share one compiled program, as in the dense kernels.
     """
     nrb, nt, bm, bk = data.shape
     out_dtype = out_dtype or data.dtype
     kernel = functools.partial(
         _spmm_sketch_kernel, nt=nt, bk=bk, sp=s_padded, s=s, kind=kind
     )
-    sd = jnp.asarray(seed, jnp.uint32).reshape(1, 1)
+    sd = jnp.asarray(seed, jnp.uint32).reshape(1)
     return pl.pallas_call(
         kernel,
-        grid=(nrb, nt),
-        in_specs=[
-            pl.BlockSpec((1, 1), lambda i, t: (i, t), memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, 1), lambda i, t: (0, 0), memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, 1, bm, bk), lambda i, t: (i, t, 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((bm, s_padded), lambda i, t: (i, 0)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(nrb, nt),
+            in_specs=[pl.BlockSpec((1, 1, bm, bk),
+                                   lambda i, t, cols, sd: (i, t, 0, 0))],
+            out_specs=pl.BlockSpec((bm, s_padded), lambda i, t, cols, sd: (i, 0)),
+            scratch_shapes=[pltpu.VMEM((bm, s_padded), jnp.float32)],
+        ),
         out_shape=jax.ShapeDtypeStruct((nrb * bm, s_padded), out_dtype),
-        scratch_shapes=[pltpu.VMEM((bm, s_padded), jnp.float32)],
         interpret=interpret,
-    )(tilecols, sd, data)
+    )(tilecols.reshape(-1), sd, data)

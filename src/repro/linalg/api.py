@@ -47,6 +47,28 @@ from repro.linalg.spec import Rank, Spec, as_spec
 SVDResult = Tuple[jax.Array, jax.Array, jax.Array]
 
 
+def f32_matmuls(fn):
+    """Run `fn` with f32 matmuls at full f32 precision, unless the caller
+    has set `jax_default_matmul_precision` itself.
+
+    A TPU multiplies f32 matrices in one bf16 pass by default (about three
+    significant digits), which caps a factorization's accuracy near 1e-3
+    whatever its rank; the accuracy contracts here (Rank residuals,
+    Tolerance and Energy targets) assume f32 arithmetic.  jax reads the
+    setting while it traces, so it reaches the Pallas kernels' dots too,
+    and it is part of the jit cache key.  The CPU computes f32 matmuls in
+    f32 either way."""
+
+    @functools.wraps(fn)
+    def wrapper(*args, **kwargs):
+        if jax.config.jax_default_matmul_precision is not None:
+            return fn(*args, **kwargs)
+        with jax.default_matmul_precision("highest"):
+            return fn(*args, **kwargs)
+
+    return wrapper
+
+
 def plan(op, spec, budget: Optional[Budget] = None,
          overrides: Optional[RSVDConfig] = None, kind: str = "svd",
          nnz: Optional[int] = None, guard=None,
@@ -103,6 +125,7 @@ class Decomposition:
         return len(self.factors)
 
 
+@f32_matmuls
 def decompose(
     a,
     spec,
@@ -204,6 +227,7 @@ def _dense_array(op: LinOp) -> jax.Array:
     return op.array if isinstance(op.array, jax.Array) else jnp.asarray(op.array)
 
 
+@f32_matmuls
 def svd(
     a,
     k: int,
@@ -303,6 +327,7 @@ def _execute_svd_plan(op: LinOp, k: int, pl: ExecutionPlan, seed) -> SVDResult:
     raise ValueError(f"unknown execution path: {pl.path}")
 
 
+@f32_matmuls
 def eigvals(
     a,
     k: int,
@@ -410,6 +435,7 @@ def _pca_centered_dense(X: jax.Array, seed: jax.Array, k: int, pl: ExecutionPlan
     return mu, S, Vt
 
 
+@f32_matmuls
 def pca(x, k, *, overrides: Optional[RSVDConfig] = None,
         budget: Optional[Budget] = None, seed: int = 0):
     """Top-k principal components of X (N x d) via the CenteredOp source.
@@ -461,6 +487,7 @@ def pca(x, k, *, overrides: Optional[RSVDConfig] = None,
 # Panel-wise residual: relative Frobenius error without an m x n temporary
 # ---------------------------------------------------------------------------
 
+@f32_matmuls
 def residual(a, result: SVDResult, block_rows: Optional[int] = None) -> jax.Array:
     """||A - U S Vt||_F / ||A||_F accumulated one row panel at a time.
 

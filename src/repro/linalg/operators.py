@@ -263,8 +263,11 @@ class SparseOp(LinOp):
     (A is never densified); `sketch` takes the fused path — a Pallas kernel
     (kernels/spmm_sketch.py) that streams block-ELL tiles of A and generates
     the matching Omega tiles in VMEM from the counter RNG, so Omega never
-    touches HBM.  Off-TPU (interpret mode aside) or for structured sketch
-    kinds it falls back to a materialized-Omega SpMM.
+    touches HBM (on the CPU backend the kernel runs in Pallas interpret
+    mode).  Structured sketch kinds, f64 sources, and matrices whose
+    block-ELL padding would pass `MAX_PACK_FILL` take a materialized-Omega
+    SpMM instead; a kernel the TPU compiler refuses raises, it is never
+    swapped for that SpMM.
 
     `row_panels` inherits the basis-slice fallback — each panel is one
     nnz-proportional `rmatmat`, so panel walks (residuals, column means)

@@ -40,23 +40,22 @@ PATHS = ("dense", "streamed", "batched", "sharded", "matfree", "adaptive", "spar
 class Budget:
     """Hardware envelope the planner fits a solve into.
 
-    Unset fields resolve to the single source of truth — the per-kernel
-    VMEM working-set budget (kernels/power_step.py) and the TPU-v5e HBM
-    size (roofline/hw.py) — so a partially-specified Budget can never
-    freeze a stale copy of either constant.  `vmem_bytes` can only
-    TIGHTEN the fusion gate: the fused body re-checks the compiled-in
-    budget at trace time, so a plan claiming fusion past it would lie
-    about what executes (see `_effective_fused_power`)."""
+    Unset fields resolve to the single source of truth, roofline/hw.py:
+    the VMEM the fused kernels compile with and the HBM size — so a
+    partially-specified Budget can never freeze a stale copy of either
+    constant.  `vmem_bytes` can only TIGHTEN the fusion gate: the fused
+    body re-checks the compiled-in limit at trace time, so a plan claiming
+    fusion past it would lie about what executes (see
+    `_effective_fused_power`)."""
 
     vmem_bytes: Optional[int] = None
     hbm_bytes: Optional[int] = None
 
     def __post_init__(self):
-        from repro.kernels.power_step import VMEM_BUDGET_BYTES
         from repro.roofline import hw
 
         if self.vmem_bytes is None:
-            object.__setattr__(self, "vmem_bytes", VMEM_BUDGET_BYTES)
+            object.__setattr__(self, "vmem_bytes", hw.VMEM_BYTES)
         if self.hbm_bytes is None:
             object.__setattr__(self, "hbm_bytes", hw.HBM_BYTES)
 
@@ -274,17 +273,17 @@ def _effective_fused_power(m: int, n: int, s: int, dtype, cfg: RSVDConfig,
     """The dense body's fusion gate, evaluated at plan time.  Delegates to
     the SAME predicate the dense body uses (core.rsvd._use_fused_power,
     parameterized by the plan's VMEM budget) so plan and execution can
-    never drift apart.  The budget is clamped to the kernel's compiled-in
-    VMEM_BUDGET_BYTES: the body re-checks that constant at trace time, so
-    a looser Budget must not make the plan claim a fusion that would not
+    never drift apart.  The budget is clamped to the kernels' compiled-in
+    limit, hw.VMEM_BYTES: the body re-checks that constant at trace time,
+    so a looser Budget must not make the plan claim a fusion that would not
     actually execute."""
     if path != "dense":
         return False  # vmap (batched) and panel/shard bodies never fuse power
     from repro.core.rsvd import _use_fused_power
-    from repro.kernels.power_step import VMEM_BUDGET_BYTES
+    from repro.roofline import hw
 
     shape = jax.ShapeDtypeStruct((m, n), dtype)
-    vmem = min(budget.vmem_bytes, VMEM_BUDGET_BYTES)
+    vmem = min(budget.vmem_bytes, hw.VMEM_BYTES)
     return _use_fused_power(shape, cfg, s, vmem_budget=vmem)
 
 

@@ -26,10 +26,10 @@ from repro.optim import adamw
 Params = Any
 
 # Seeds are traced through the counter RNG, so per-refresh/per-unit seeds
-# reuse one compiled program on every path.  fused_sketch now vmaps too
-# (traced SMEM seed) and is worth enabling on real TPUs; the default stays
-# off because off-TPU it runs in Pallas interpret mode (~18x slower than
-# the XLA GEMM for zero HBM benefit).
+# reuse one compiled program on every path.  fused_sketch vmaps too (traced
+# SMEM seed) and compiles for the TPU; the default stays off because on the
+# CPU backend the kernel runs in Pallas interpret mode (~18x slower than
+# the XLA GEMM for zero HBM benefit), and its TPU time is not measured.
 _RSVD_CFG = RSVDConfig(oversample=8, power_iters=1, qr_method="cqr2", small_svd="gram")
 
 

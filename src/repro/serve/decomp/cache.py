@@ -22,6 +22,7 @@ from typing import Callable, Dict, Tuple
 import jax
 
 from repro.core import blocked
+from repro.linalg.api import f32_matmuls
 from repro.linalg.planner import ExecutionPlan
 
 
@@ -61,6 +62,7 @@ class ExecutableCache:
         cfg = pl.to_config()
         k = pl.k
 
+        @f32_matmuls
         def solve(stack: jax.Array, seeds: jax.Array):
             return blocked.svd_batched(stack, k, cfg, seed=seeds)
 

@@ -13,6 +13,7 @@ def _run_driver(name: str) -> str:
     env = dict(os.environ)
     env["PYTHONPATH"] = str(ROOT / "src")
     env.pop("XLA_FLAGS", None)  # driver sets its own device count
+    env["JAX_PLATFORMS"] = "cpu"  # a child never takes the chip
     proc = subprocess.run(
         [sys.executable, str(ROOT / "tests" / name)],
         capture_output=True,
@@ -57,8 +58,6 @@ def test_distributed_rsvd_inprocess_multidevice():
     from repro.core.spectra import make_test_matrix
 
     n_dev = len(jax.devices())
-    # jax.sharding.Mesh directly: jax.make_mesh does not exist on the older
-    # jax lines repro.compat still supports.
     mesh = jax.sharding.Mesh(np.array(jax.devices()), ("data",))
     A, sig = make_test_matrix(32 * n_dev, 64, "fast", seed=0)
     A_sharded = jax.device_put(A, NamedSharding(mesh, P("data", None)))

@@ -134,13 +134,14 @@ def test_fused_power_vmem_guard_falls_back():
     the unfused body (the kernel would not compile on real hardware)."""
     from repro.core import RSVDConfig
     from repro.core.rsvd import _use_fused_power
-    from repro.kernels.power_step import VMEM_BUDGET_BYTES, fused_power_vmem_bytes
+    from repro.kernels.power_step import fused_power_vmem_bytes
+    from repro.roofline import hw
 
     cfg = RSVDConfig.fast()
     small = jnp.zeros((512, 256), jnp.float32)
     assert _use_fused_power(small, cfg, s=34)
-    big = jax.ShapeDtypeStruct((8192, 8192), jnp.float32)
-    assert fused_power_vmem_bytes(8192, 266) > VMEM_BUDGET_BYTES
+    big = jax.ShapeDtypeStruct((16384, 16384), jnp.float32)
+    assert fused_power_vmem_bytes(16384, 384) > hw.VMEM_BYTES
     assert not _use_fused_power(big, cfg, s=266)
 
 

@@ -189,3 +189,30 @@ def test_residual_stacked_tolerates_zero_slice():
     Vt = jnp.zeros((2, 3, 12))
     got = float(linalg.residual(A, (U, S, Vt)))
     assert np.isfinite(got) and np.isclose(got, 1.0)  # zero factors -> err = 1
+
+
+def _dot_precisions(jaxpr):
+    out = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "dot_general":
+            out.append(eqn.params["precision"])
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            out.extend(_dot_precisions(sub))
+    return out
+
+
+@pytest.mark.parametrize("caller", [None, "bfloat16"])
+def test_entry_points_trace_f32_matmuls_at_highest(caller):
+    """The facade traces its matmuls at full f32 precision (a TPU's default
+    f32 matmul is one bf16 pass) unless the caller chose a precision."""
+    A, _ = make_test_matrix(64, 16, "fast", seed=40)
+    res = (jnp.ones((64, 2)), jnp.ones((2,)), jnp.ones((2, 16)))
+    if caller is None:
+        jaxpr = jax.make_jaxpr(lambda a: linalg.residual(a, res))(A)
+        want = jax.lax.Precision.HIGHEST
+    else:
+        with jax.default_matmul_precision(caller):
+            jaxpr = jax.make_jaxpr(lambda a: linalg.residual(a, res))(A)
+        want = jax.lax.Precision.DEFAULT
+    precisions = _dot_precisions(jaxpr.jaxpr)
+    assert precisions and all(p == (want, want) for p in precisions), precisions

@@ -3,7 +3,7 @@
 Golden table: (source, dtype, overrides) -> expected ExecutionPlan fields,
 including the paper's benchmark shapes, where `plan()` must reproduce the
 historical `fast()` / `streaming()` dispatch decisions (the VMEM gate that
-un-fuses the power step at 8192x8192 included).  Plans are shape-only, so
+un-fuses the power step at 16384x16384 included).  Plans are shape-only, so
 the big shapes use jax.ShapeDtypeStruct — nothing is allocated.
 
 Properties:
@@ -42,7 +42,12 @@ GOLDEN = [
     ("fast_2000", lambda: linalg.DenseOp(_sds(2000, 2000)), RSVDConfig.fast(), 90,
      dict(path="dense", fused_power=True, fused_sketch=True,
           kernel_backend="pallas", qr_method="cqr2", s=100, pipeline_depth=1)),
+    # 8192², s=256 needs ~74 MiB of the 128 MiB the kernels compile with
     ("fast_8192_vmem_gate", lambda: linalg.DenseOp(_sds(8192, 8192)), RSVDConfig.fast(), 246,
+     dict(path="dense", fused_power=True, fused_sketch=True,
+          kernel_backend="pallas", s=256)),
+    # 16384², s=256 needs ~146 MiB: past the limit, the power step un-fuses
+    ("fast_16384_vmem_gate", lambda: linalg.DenseOp(_sds(16384, 16384)), RSVDConfig.fast(), 246,
      dict(path="dense", fused_power=False, fused_sketch=True,
           kernel_backend="pallas", s=256)),
     ("fast_65536x4096", lambda: linalg.DenseOp(_sds(65536, 4096)), RSVDConfig.fast(), 118,
@@ -148,7 +153,7 @@ def test_plan_vmem_budget_cannot_loosen_past_kernel_limit():
     """A LOOSER budget must not make the plan claim a fusion the dense
     body's compiled-in VMEM gate would refuse at trace time (the plan is a
     record of what executes, never a wish)."""
-    op = linalg.DenseOp(_sds(8192, 8192))
+    op = linalg.DenseOp(_sds(16384, 16384))
     loose = linalg.Budget(vmem_bytes=1 << 30)
     pl = linalg.plan(op, 246, budget=loose, overrides=RSVDConfig.fast())
     assert not pl.fused_power

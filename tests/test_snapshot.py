@@ -434,6 +434,7 @@ def _run_driver(mode, workdir):
     env = os.environ.copy()
     env["PYTHONPATH"] = str(ROOT / "src")
     env.pop("XLA_FLAGS", None)
+    env["JAX_PLATFORMS"] = "cpu"  # a child never takes the chip
     proc = subprocess.run(
         [sys.executable, str(ROOT / "tests" / "resume_driver.py"),
          mode, str(workdir)],
