@@ -1,0 +1,33 @@
+"""The control: the plain reference in the program's place, run through the
+harness's own comparison under each cell's committed limits, at the cell's
+own configuration on the CPU.  With its products at one bf16 pass it must
+come out not correct; at `highest` it must pass."""
+from __future__ import annotations
+
+import json
+import shutil
+
+import pytest
+
+from test_rehearsal import BENCH, ROOT, run_cell
+
+CELLS = [w["name"] for w in json.loads((ROOT / "BENCHMARK.json").read_text())["workloads"]]
+
+
+def checkout(tmp_path):
+    root = tmp_path / "checkout"
+    shutil.copytree(BENCH, root / "bench", ignore=shutil.ignore_patterns("tests", "__pycache__"))
+    shutil.copy(ROOT / "BENCHMARK.json", root)
+    return root
+
+
+@pytest.mark.parametrize("precision", ["bf16", "highest"])
+@pytest.mark.parametrize("cell", CELLS)
+def test_control_fails_and_reference_passes(tmp_path, cell, precision):
+    root = checkout(tmp_path)
+    patch = f"import control; control.plant({cell!r}, {precision!r}, run.ROOT)"
+    result = run_cell(root, cell, patch=patch, seconds=3.0)
+    limits = json.loads((BENCH / "limits" / f"{cell}.json").read_text())
+    assert {name: c["limit"] for name, c in result["check"].items()} == limits
+    assert result["failed"] == 0
+    assert result["correct"] == (precision == "highest"), result["check"]

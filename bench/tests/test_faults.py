@@ -1,0 +1,102 @@
+"""A run whose timed path is broken must come out as not correct.
+
+Each case runs the harness on the CPU at a tiny size with the check for a
+chip skipped and one fault planted under `repro.linalg.decompose`:
+
+- stale: every call returns the first call's factors (a step that returns
+  its state unchanged);
+- half_rows: the solve sees only the first half of A's rows, the rest read
+  as zero (half of the batch left out);
+- altered: the singular values are off by 1% where they are produced;
+- lost_direction: the last right singular vector comes back as a copy of
+  the first (V^T no longer orthonormal; the residual barely moves);
+- one_pass_qr, gram_small_svd: the program's own cheaper paths, one-pass
+  CholeskyQR for its range finder and the Gram eigendecomposition for its
+  small SVD (U, and V, no longer orthonormal);
+- memo: every matrix's first result is kept and returned again for it,
+  whatever the sketch seed (no cache may serve a call: the check holds two
+  solves of one matrix to differ).
+"""
+from __future__ import annotations
+
+import pytest
+
+from test_rehearsal import make_checkout, run_cell
+
+WRAP = """
+import jax, jax.numpy as jnp, numpy as np
+from repro import linalg
+_real = linalg.decompose
+"""
+
+#: the program's own default plan with one field of its configuration
+#: changed, to a cheaper path of its own
+VARIANT = """
+import dataclasses
+from repro.linalg import planner
+def broken(a, spec, **kw):
+    op = linalg.as_linop(a)
+    cfg = planner._default_config(op, planner._pick_path(op, None),
+                                  planner.Budget.default())
+    return _real(a, spec, overrides=dataclasses.replace(cfg, %s=%r), **kw)
+linalg.decompose = broken
+"""
+
+FAULTS = {
+    "stale": WRAP + """
+_first = []
+def broken(a, spec, **kw):
+    if not _first:
+        _first.append(_real(a, spec, **kw))
+    return _first[0]
+linalg.decompose = broken
+""",
+    "half_rows": WRAP + """
+def broken(a, spec, **kw):
+    half = a.shape[0] // 2
+    if isinstance(a, np.ndarray):
+        a = np.concatenate([a[:half], np.zeros_like(a[half:])])
+    else:
+        a = jnp.asarray(a).at[half:].set(0)
+    return _real(a, spec, **kw)
+linalg.decompose = broken
+""",
+    "altered": WRAP + """
+import dataclasses
+def broken(a, spec, **kw):
+    dec = _real(a, spec, **kw)
+    U, S, Vt = dec.factors
+    return dataclasses.replace(dec, factors=(U, S * 1.01, Vt))
+linalg.decompose = broken
+""",
+    "lost_direction": WRAP + """
+import dataclasses
+def broken(a, spec, **kw):
+    dec = _real(a, spec, **kw)
+    U, S, Vt = dec.factors
+    return dataclasses.replace(dec, factors=(U, S, Vt.at[-1].set(Vt[0])))
+linalg.decompose = broken
+""",
+    "one_pass_qr": WRAP + VARIANT % ("qr_method", "cqr"),
+    "gram_small_svd": WRAP + VARIANT % ("small_svd", "gram"),
+    "memo": WRAP + """
+_kept = {}
+def broken(a, spec, **kw):
+    if id(a) not in _kept:
+        _kept[id(a)] = _real(a, spec, **kw)
+    return _kept[id(a)]
+linalg.decompose = broken
+""",
+}
+
+
+@pytest.mark.parametrize("fault,residency", [
+    ("stale", "device"), ("half_rows", "device"), ("altered", "device"),
+    ("lost_direction", "device"), ("one_pass_qr", "device"),
+    ("gram_small_svd", "device"), ("memo", "device"), ("half_rows", "host"),
+    ("gram_small_svd", "host"), ("memo", "host")])
+def test_fault_is_not_correct(tmp_path, fault, residency):
+    root = make_checkout(tmp_path, residency)
+    result = run_cell(root, f"tiny.{residency}", patch=FAULTS[fault])
+    assert result["failed"] == 0
+    assert not result["correct"], result["check"]

@@ -1,0 +1,131 @@
+"""A data-only rehearsal of the harness on the CPU, at a tiny size.
+
+A throwaway configuration, traffic mix, limits file and cell are written
+into a copy of the benchmark in a temporary directory; the harness finds
+them by name and runs them with no code edit.  The command itself refuses
+to measure on the CPU.
+
+    JAX_PLATFORMS=cpu python -m pytest -q bench/tests
+"""
+from __future__ import annotations
+
+import json
+import os
+import shutil
+import subprocess
+import sys
+from pathlib import Path
+
+import pytest
+
+BENCH = Path(__file__).resolve().parents[1]
+ROOT = BENCH.parent
+sys.path.insert(0, str(BENCH))
+
+TINY_CONFIG = {
+    "name": "tiny_2048x256", "source": "rehearsal only",
+    "m": 2048, "n": 256, "dtype": "float32", "spectrum": "inverse_square",
+    "rank": 20, "oversample": 10, "power_iters": 2,
+    "reduced": [], "assumed": [],
+}
+#: the numbers every cell compares, at limits a sound tiny run keeps
+LIMITS = {"residual_ratio": 1.1, "u_orth_err": 1e-4, "v_orth_err": 1e-4,
+          "sigma_rel_err_top1": 1e-4, "sigma_rel_err_top10": 1e-3,
+          "repeat_identical": 0}
+
+
+def make_checkout(tmp: Path, residency: str, limits=None) -> Path:
+    """A copy of the benchmark plus one new cell, `tiny.<residency>`,
+    added as data files and BENCHMARK.json entries only."""
+    root = tmp / "checkout"
+    shutil.copytree(BENCH, root / "bench",
+                    ignore=shutil.ignore_patterns("tests", "__pycache__"))
+    bench = json.loads((ROOT / "BENCHMARK.json").read_text())
+    cell = f"tiny.{residency}"
+    traffic = f"closed.tiny_{residency}"
+    bench["configs"].append({"name": TINY_CONFIG["name"], "source": "rehearsal",
+                             "file": "bench/configs/tiny_2048x256.json",
+                             "reduced": [], "why": "rehearsal"})
+    bench["workloads"].append({"name": cell, "config": TINY_CONFIG["name"],
+                               "traffic": traffic, "chips": 1, "why": "rehearsal"})
+    for m in bench["per_layer"]:
+        m["workloads"].append(cell)
+    (root / "BENCHMARK.json").write_text(json.dumps(bench))
+    (root / "bench/configs/tiny_2048x256.json").write_text(json.dumps(TINY_CONFIG))
+    (root / f"bench/traffic/{traffic}.json").write_text(json.dumps({
+        "name": traffic, "loop": "closed", "residency": residency, "pool": 3,
+        "warmup_calls": 3, "check_sample": 4, "link_probe_rows": 256}))
+    (root / f"bench/limits/{cell}.json").write_text(json.dumps(limits or LIMITS))
+    return root
+
+
+def run_cell(root: Path, workload: str, trace: int = 0, seed: int = 2**31 + 7,
+             patch: str = "", seconds: float = 1.0) -> dict:
+    """Run the harness in a child process on the CPU, with `patch`
+    executed first; return its result."""
+    code = (
+        "import sys; sys.path.insert(0, %r); sys.argv[0] = 'run.py'\n"
+        "import run\n%s\n"
+        "sys.exit(run.main(['--workload', %r, '--seed', '%d', "
+        "'--seconds', '%r', '--trace', '%d'], root=__import__('pathlib').Path(%r), "
+        "require_tpu=False))\n"
+    ) % (str(BENCH), patch, workload, seed, seconds, trace, str(root))
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    proc = subprocess.run([sys.executable, "-c", code], env=env, cwd=root,
+                          capture_output=True, text=True, timeout=600)
+    assert proc.returncode == 0, proc.stderr[-4000:]
+    last = proc.stdout.strip().splitlines()[-1]
+    result = json.loads(last)
+    assert list(result)[-1] == "check"
+    tail = proc.stderr.strip().splitlines()[-len(result["check"]):]
+    assert all(line.startswith("check ") for line in tail), tail
+    return result
+
+
+@pytest.mark.parametrize("residency", ["device", "host"])
+def test_new_cell_runs_from_data_alone(tmp_path, residency):
+    root = make_checkout(tmp_path, residency)
+    result = run_cell(root, f"tiny.{residency}")
+    assert result["correct"], result
+    assert result["failed"] == 0 and result["attempted"] >= 1
+    assert set(result["metrics"]) == {"solves_per_s", "setup_s"}
+    assert result["device"]["count"] == 1
+    checked = result["check"]
+    assert set(checked) == set(LIMITS)
+    assert checked["residual_ratio"]["value"] < 1.1
+    assert checked["repeat_identical"]["value"] == 0
+
+
+def test_per_call_sketch_seeds_fit_a_signed_32_bit_int():
+    import loadgen
+
+    traffic = {"pool": 17}
+    run_seed = 2 ** 32 + 2 ** 31 - 2
+    calls = [loadgen.call(traffic, run_seed, i) for i in range(4)]
+    assert [seed for _, seed in calls] == [2 ** 31 - 2, 2 ** 31 - 1, 0, 1]
+    assert [member for member, _ in calls] == [0, 1, 2, 3]
+
+
+def test_command_refuses_the_cpu():
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    proc = subprocess.run(
+        [sys.executable, "bench/run.py", "--workload", "dense.paper", "--seed", "3",
+         "--seconds", "1", "--trace", "0"],
+        env=env, cwd=ROOT, capture_output=True, text=True, timeout=300)
+    assert proc.returncode != 0
+    assert "{" not in proc.stdout
+    assert "needs a TPU" in proc.stderr
+
+
+def test_command_refuses_a_checkout_without_the_program(tmp_path):
+    """Only BENCHMARK.json and bench/: the program is missing."""
+    shutil.copytree(BENCH, tmp_path / "bench",
+                    ignore=shutil.ignore_patterns("tests", "__pycache__"))
+    shutil.copy(ROOT / "BENCHMARK.json", tmp_path)
+    proc = subprocess.run(
+        [sys.executable, "bench/run.py", "--workload", "dense.paper", "--seed", "3",
+         "--seconds", "1", "--trace", "0"],
+        env=dict(os.environ, JAX_PLATFORMS="cpu"), cwd=tmp_path,
+        capture_output=True, text=True, timeout=300)
+    assert proc.returncode != 0
+    assert "{" not in proc.stdout
