@@ -6,7 +6,6 @@
   bench_sumc      -> Table 1  (SuMC subspace clustering, solver swap)
   bench_kernels   -> kernel microbenches + fused-sketch HBM-traffic model
   bench_rsvd      -> rSVD variants + fused-power traffic model -> BENCH_rsvd.json
-  roofline_report -> §Roofline terms from the dry-run artifacts
 """
 import pathlib
 import sys
@@ -22,7 +21,7 @@ def main() -> None:
 
     compile_cache.enable()
     from benchmarks import bench_kernels, bench_pca, bench_rsvd, bench_spectra
-    from benchmarks import bench_sumc, roofline_report
+    from benchmarks import bench_sumc
 
     modules = [
         ("spectra", bench_spectra),
@@ -30,7 +29,6 @@ def main() -> None:
         ("sumc", bench_sumc),
         ("kernels", bench_kernels),
         ("rsvd", bench_rsvd),
-        ("roofline", roofline_report),
     ]
     failures = 0
     for name, mod in modules:

@@ -23,7 +23,7 @@ asserts the contracts the roofline model prices:
                          jaxpr equals the pass count `rsvd_model` charges
                          for: 1+q fused, 2+2q unfused (sparse: SpMM count).
   C5 trace-accounting    a second identical batched solve must not re-trace
-                         (`blocked._TRACE_COUNTS` moves by at most one per
+                         (`blocked.trace_count` moves by at most one per
                          plan, then stays put).
 
 Tracing is tag-based: each traced input is tagged, view primitives

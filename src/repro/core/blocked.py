@@ -48,10 +48,8 @@ bit-identical to the synchronous, undonated walk (DESIGN.md §Pipeline).
 """
 from __future__ import annotations
 
-import collections
 import dataclasses
 import functools
-import threading
 from typing import List, Sequence, Tuple
 
 import jax
@@ -72,8 +70,13 @@ def _panel_bounds(m: int, b: int) -> List[Tuple[int, int]]:
     return panel_bounds(m, b)
 
 
-def _device(panel) -> jax.Array:
-    """Move one panel to device (no-op for arrays already there)."""
+def _device(panel, idx: int = 0) -> jax.Array:
+    """Move one host panel to the device through the pipeline's put (traced
+    and counted there); arrays already on the device pass through."""
+    if isinstance(panel, np.ndarray):
+        from repro.linalg import pipeline as pipe  # lazy: core stays cycle-free
+
+        return pipe.put_panel(panel, idx)
     return jnp.asarray(panel)
 
 
@@ -82,9 +85,9 @@ def _panel_stream(A, bounds, depth):
 
     Each call starts one pass; with resolved depth > 1 the host->device copy
     of panel i+1 is issued while panel i computes (linalg/pipeline.py) —
-    host numpy sources take the staged ring, device arrays degrade to the
-    plain lazy-slice walk.  Values and order are bit-identical to the
-    synchronous walk either way.
+    host numpy sources take the staged ring (its synchronous walk at depth
+    1), device arrays the plain lazy-slice walk.  Values and order are
+    bit-identical to the synchronous walk either way.
 
     ``start`` begins the pass at panel ordinal ``start`` (a resumed solve
     re-walks only the panels its restored cursor has not consumed; probe /
@@ -93,10 +96,10 @@ def _panel_stream(A, bounds, depth):
 
     host = isinstance(A, np.ndarray)
     d = pipe.resolve_depth(depth, host_resident=host)
-    if host and d > 1:
+    if host:
         return lambda start=0: pipe.stream_host_panels(A, bounds[start:], d)
     return lambda start=0: pipe.lookahead(
-        (_device(A[lo:hi]) for lo, hi in bounds[start:]), d)
+        (A[lo:hi] for lo, hi in bounds[start:]), d)
 
 
 # ---------------------------------------------------------------------------
@@ -164,8 +167,8 @@ def streamed_sketch(
     m, n = A.shape
     b = block_cols or n
     Y = jnp.zeros((m, s), jnp.float32)
-    for lo, hi in _panel_bounds(n, b):
-        panel = _device(A[:, lo:hi])
+    for j, (lo, hi) in enumerate(_panel_bounds(n, b)):
+        panel = _device(A[:, lo:hi], j)
         if fused:
             from repro.kernels.ops import sketch_matmul
 
@@ -177,7 +180,7 @@ def streamed_sketch(
                 hi - lo, s, seed, kind, dtype=jnp.float32, row_offset=lo
             )
             Y = _add_donated(Y, panel.astype(jnp.float32) @ omega)
-    return Y.astype(jnp.asarray(A[:1, :1]).dtype)
+    return Y.astype(jax.dtypes.canonicalize_dtype(A.dtype))
 
 
 # ---------------------------------------------------------------------------
@@ -297,7 +300,7 @@ def svd_streamed(
     depth = pipeline_depth if pipeline_depth is not None else cfg.pipeline_depth
     panels = _panel_stream(A, bounds, depth)
 
-    dtype = _device(A[:1, :1]).dtype
+    dtype = jax.dtypes.canonicalize_dtype(A.dtype)
     token = _stream_token(m, n, k, s, cfg, seed, dtype, nb=len(bounds))
 
     with qr_mod.kernel_backend(cfg.kernel_backend):
@@ -407,7 +410,11 @@ def _blocked_body(panels, k: int, s: int, cfg: RSVDConfig, seed, dtype,
 
     ``token`` (from `_stream_token`) enables snapshot/resume; None (direct
     callers, tests of the raw body) runs the fresh stage machine with
-    boundaries still crossed — identical arithmetic either way."""
+    boundaries still crossed — identical arithmetic either way.  Each pass
+    over A and each step between passes runs under its `engine.*` span
+    (linalg/trace.py)."""
+    from repro.linalg.trace import span  # lazy: core stays cycle-free
+
     st = (_stream_resume(token) if token is not None else None) \
         or _StreamState(token or "")
     _panel_orth = _panel_orthonormalizer(cfg)
@@ -421,22 +428,23 @@ def _blocked_body(panels, k: int, s: int, cfg: RSVDConfig, seed, dtype,
     # f64 — the faithful enable_x64 setting — stays on the jnp sketch, like
     # the dense path's guard.)
     if st.stage == "sketch":
-        if cfg.fused_sketch and not cfg.block_cols and dtype != jnp.float64:
-            from repro.kernels.ops import sketch_gram
+        with span("engine.sketch"):
+            if cfg.fused_sketch and not cfg.block_cols and dtype != jnp.float64:
+                from repro.kernels.ops import sketch_gram
 
-            for Ap in panels(st.cursor):
-                y, g = sketch_gram(Ap, s, seed, kind=cfg.sketch_kind)
-                st.Y.append(y)
-                st.G1 = g if st.G1 is None else _add_donated(st.G1, g)
-                _stream_boundary(st)
-        else:
-            for Ap in panels(st.cursor):
-                st.Y.append(streamed_sketch(
-                    Ap, s, seed, cfg.sketch_kind,
-                    block_cols=cfg.block_cols,
-                    fused=cfg.fused_sketch and dtype != jnp.float64,
-                ))
-                _stream_boundary(st)
+                for Ap in panels(st.cursor):
+                    y, g = sketch_gram(Ap, s, seed, kind=cfg.sketch_kind)
+                    st.Y.append(y)
+                    st.G1 = g if st.G1 is None else _add_donated(st.G1, g)
+                    _stream_boundary(st)
+            else:
+                for Ap in panels(st.cursor):
+                    st.Y.append(streamed_sketch(
+                        Ap, s, seed, cfg.sketch_kind,
+                        block_cols=cfg.block_cols,
+                        fused=cfg.fused_sketch and dtype != jnp.float64,
+                    ))
+                    _stream_boundary(st)
         st.stage = "power_z" if cfg.power_iters else "project"
         st.cursor = 0
 
@@ -451,19 +459,23 @@ def _blocked_body(panels, k: int, s: int, cfg: RSVDConfig, seed, dtype,
             else:
                 # recomputed (not snapshotted) on resume: a deterministic
                 # function of the saved Y panels + Gram, same ops
-                src, _ = _panel_orth(st.Y, st.G1)
-            for Ap, Xp in zip(panels(st.cursor), src[st.cursor:]):
-                st.Z = Ap.T @ Xp if st.Z is None else _accum_xty(st.Z, Ap, Xp)
-                _stream_boundary(st)
+                with span("engine.orth"):
+                    src, _ = _panel_orth(st.Y, st.G1)
+            with span("engine.power_z"):
+                for Ap, Xp in zip(panels(st.cursor), src[st.cursor:]):
+                    st.Z = Ap.T @ Xp if st.Z is None else _accum_xty(st.Z, Ap, Xp)
+                    _stream_boundary(st)
             st.stage, st.cursor, st.Y = "power_y", 0, []
         else:  # power_y: rebuild Y from the completed Z accumulator
             if cfg.power_scheme == "plain":
                 mult = st.Z
             else:
-                mult = qr_mod.orthonormalize(st.Z, cfg.qr_method)  # n x s, fits
-            for Ap in panels(st.cursor):
-                st.Y.append(Ap @ mult)
-                _stream_boundary(st)
+                with span("engine.orth"):
+                    mult = qr_mod.orthonormalize(st.Z, cfg.qr_method)  # n x s, fits
+            with span("engine.power_y"):
+                for Ap in panels(st.cursor):
+                    st.Y.append(Ap @ mult)
+                    _stream_boundary(st)
             st.G1 = None  # Y was replaced; the sketch-pass Gram is stale
             st.Z = None
             st.piter += 1
@@ -472,15 +484,19 @@ def _blocked_body(panels, k: int, s: int, cfg: RSVDConfig, seed, dtype,
 
     # Steps 3-4: orthonormal range basis (panel-split; recomputed from the
     # saved Y/G1 on resume), then B = Q^T A through the s x n accumulator.
-    Q, _ = _panel_orth(st.Y, st.G1)
-    for Ap, Qp in zip(panels(st.cursor), Q[st.cursor:]):
-        st.B = Qp.T @ Ap if st.B is None else _accum_xty(st.B, Qp, Ap)
-        _stream_boundary(st)
+    with span("engine.orth"):
+        Q, _ = _panel_orth(st.Y, st.G1)
+    with span("engine.project"):
+        for Ap, Qp in zip(panels(st.cursor), Q[st.cursor:]):
+            st.B = Qp.T @ Ap if st.B is None else _accum_xty(st.B, Qp, Ap)
+            _stream_boundary(st)
 
     # Steps 5-6: small SVD (s x n, in-memory) and per-panel U assembly.
-    U_b, S, Vt = _small_svd(st.B, cfg.small_svd)
-    U = jnp.concatenate([Qp @ U_b[:, :k] for Qp in Q], axis=0)
-    return U, S[:k], Vt[:k, :]
+    with span("engine.small_svd"):
+        U_b, S, Vt = _small_svd(st.B, cfg.small_svd)
+    with span("engine.assemble"):
+        U = jnp.concatenate([Qp @ U_b[:, :k] for Qp in Q], axis=0)
+        return U, S[:k], Vt[:k, :]
 
 
 def eigvals_streamed(
@@ -497,30 +513,33 @@ def eigvals_streamed(
 # Batched (vmap) randomized SVD
 # ---------------------------------------------------------------------------
 
-#: trace-time tally: (shape, dtype, k, cfg) -> how many times the batched
-#: body was TRACED (not executed).  Incrementing inside the function body
-#: runs at trace time only, so a jit cache hit leaves the count untouched —
-#: the serve-layer executable cache asserts steady-state re-trace-freedom
-#: (at most one trace per distinct plan) against this.  Service workers
-#: trace concurrently, so every touch goes through _trace_counts_lock
-#: (`_note_trace` / `trace_count`), keeping the tally exact under threads.
-_TRACE_COUNTS: collections.Counter = collections.Counter()
-_trace_counts_lock = threading.Lock()
-
+# Trace-time tally: how many times the batched body was TRACED (not
+# executed) per (shape, dtype, k, cfg).  The count is taken inside the
+# function body, so it ticks at trace time only and a jit cache hit leaves it
+# untouched — the serve-layer executable cache asserts steady-state
+# re-trace-freedom (at most one trace per distinct plan) against it.  It
+# lives in linalg/trace.py's thread-safe counters as `jit.traces.<key>`.
 
 def _trace_key(shape, dtype, k: int, cfg: RSVDConfig):
     return (tuple(shape), jnp.dtype(dtype).name, int(k), cfg)
 
 
+def _trace_counter(key) -> str:
+    """The counter that tallies traces of a `_trace_key`."""
+    return f"jit.traces.{key!r}"
+
+
 def _note_trace(key) -> None:
-    with _trace_counts_lock:
-        _TRACE_COUNTS[key] += 1
+    from repro.linalg import trace  # lazy: core stays cycle-free
+
+    trace.add(_trace_counter(key))
 
 
 def trace_count(key) -> int:
     """Exact number of traces recorded for a `_trace_key` (thread-safe)."""
-    with _trace_counts_lock:
-        return _TRACE_COUNTS.get(key, 0)
+    from repro.linalg import trace
+
+    return trace.count(_trace_counter(key))
 
 
 def _batched_tall_body(A: jax.Array, seeds: jax.Array, k: int, cfg: RSVDConfig):

@@ -18,6 +18,11 @@ Optimized (beyond-paper, TPU-native) switches — see DESIGN.md §2:
 
 `randomized_eigvals` implements the paper's "only the k largest eigenvalues"
 mode (steps 1-5, Sigma only), used in the PCA / spectra experiments.
+
+Each step of the bodies below runs under a `jax.named_scope` (`rsvd.sketch`,
+`rsvd.power`, `rsvd.orth`, `rsvd.project`, `rsvd.small_svd`,
+`rsvd.assemble`): the scopes change no op, and a TPU trace carries them as
+each op's `tf_op` stat (DESIGN.md §Tracing).
 """
 from __future__ import annotations
 
@@ -119,12 +124,14 @@ class RSVDConfig:
 
 
 def _small_svd(B: jax.Array, method: SmallSVD):
-    if method == "lapack":
-        return jnp.linalg.svd(B, full_matrices=False)  # repro: noqa[RL006]: B is sketch-width (s x n), Algorithm 1 step 5
-    if method == "gram":
-        return svd_via_gram(B, use_jacobi=False)
-    if method == "gram_jacobi":
-        return svd_via_gram(B, use_jacobi=True)
+    """Step 5 on every path, under the `rsvd.small_svd` scope."""
+    with jax.named_scope("rsvd.small_svd"):
+        if method == "lapack":
+            return jnp.linalg.svd(B, full_matrices=False)  # repro: noqa[RL006]: B is sketch-width (s x n), Algorithm 1 step 5
+        if method == "gram":
+            return svd_via_gram(B, use_jacobi=False)
+        if method == "gram_jacobi":
+            return svd_via_gram(B, use_jacobi=True)
     raise ValueError(f"unknown small_svd: {method}")
 
 
@@ -239,24 +246,30 @@ def _rsvd_body_fused(
     # The sketch pass already emits W = AᵀY (sketch_power strip layout), so
     # even the FIRST power iteration closes through a sketch-width TRSM
     # instead of re-reading A: reads of A = 1 + q exactly.
-    if cfg.fused_sketch and cfg.sketch_kind not in sketch_mod.STRUCTURED_KINDS:
-        Y, W, G1 = ops.sketch_power(A, s, seed, kind=cfg.sketch_kind)
-    else:
-        # structured kinds have no in-kernel RNG — materialize Omega and
-        # still take the one-pass strip kernel for Y / W / G
-        omega = sketch_mod.sketch_matrix(n, s, seed, cfg.sketch_kind, dtype=A.dtype)
-        Y, W, G1 = ops.power_step(A, omega, with_gram=True)
+    with jax.named_scope("rsvd.sketch"):
+        if cfg.fused_sketch and cfg.sketch_kind not in sketch_mod.STRUCTURED_KINDS:
+            Y, W, G1 = ops.sketch_power(A, s, seed, kind=cfg.sketch_kind)
+        else:
+            # structured kinds have no in-kernel RNG — materialize Omega and
+            # still take the one-pass strip kernel for Y / W / G
+            omega = sketch_mod.sketch_matrix(n, s, seed, cfg.sketch_kind, dtype=A.dtype)
+            Y, W, G1 = ops.power_step(A, omega, with_gram=True)
     for _ in range(cfg.power_iters):
+        with jax.named_scope("rsvd.orth"):
+            Q1, R2, R_tot = _cqr2_factor(Y, G1)
+            Z = qr_mod.tri_solve_right(W, R_tot)           # AᵀQ without reading A
+            Qz = qr_mod.orthonormalize(Z, cfg.qr_method)   # n x s, sketch-width
+        with jax.named_scope("rsvd.power"):
+            Y, W, G1 = ops.power_step(A, Qz, with_gram=True)
+    with jax.named_scope("rsvd.orth"):
         Q1, R2, R_tot = _cqr2_factor(Y, G1)
-        Z = qr_mod.tri_solve_right(W, R_tot)           # AᵀQ without reading A
-        Qz = qr_mod.orthonormalize(Z, cfg.qr_method)   # n x s, sketch-width
-        Y, W, G1 = ops.power_step(A, Qz, with_gram=True)
-    Q1, R2, R_tot = _cqr2_factor(Y, G1)
-    Q = qr_mod.tri_solve_right(Q1, R2)                 # step 3 basis
-    B = qr_mod.tri_solve_right(W, R_tot).T             # step 4 without reading A
-    U_b, S, Vt = _small_svd(B, cfg.small_svd)          # step 5
-    U = Q @ U_b                                        # step 6
-    return U[:, :k], S[:k], Vt[:k, :]
+        Q = qr_mod.tri_solve_right(Q1, R2)                 # step 3 basis
+    with jax.named_scope("rsvd.project"):
+        B = qr_mod.tri_solve_right(W, R_tot).T             # step 4 without reading A
+    U_b, S, Vt = _small_svd(B, cfg.small_svd)              # step 5
+    with jax.named_scope("rsvd.assemble"):
+        U = Q @ U_b                                        # step 6
+        return U[:, :k], S[:k], Vt[:k, :]
 
 
 def _rsvd_body(
@@ -271,18 +284,23 @@ def _rsvd_body(
     s = min(k + cfg.oversample, min(m, n))
     if _use_fused_power(A, cfg, s):
         return _rsvd_body_fused(A, k, cfg, seed)
-    Y = _sketch(A, s, seed, cfg)                       # step 1-2a: A @ Omega
+    with jax.named_scope("rsvd.sketch"):
+        Y = _sketch(A, s, seed, cfg)                   # step 1-2a: A @ Omega
     if cfg.power_iters > 0:
         if cfg.power_scheme == "plain":
-            for _ in range(cfg.power_iters):           # step 2: (A A^T)^q
-                Y = A @ (A.T @ Y)
+            with jax.named_scope("rsvd.power"):
+                for _ in range(cfg.power_iters):       # step 2: (A A^T)^q
+                    Y = A @ (A.T @ Y)
         else:
             Y = _stabilized_power(A, Y, cfg)
-    Q = qr_mod.orthonormalize(Y, cfg.qr_method)        # step 3
-    B = Q.T @ A                                        # step 4
+    with jax.named_scope("rsvd.orth"):
+        Q = qr_mod.orthonormalize(Y, cfg.qr_method)    # step 3
+    with jax.named_scope("rsvd.project"):
+        B = Q.T @ A                                    # step 4
     U_b, S, Vt = _small_svd(B, cfg.small_svd)          # step 5
-    U = Q @ U_b                                        # step 6
-    return U[:, :k], S[:k], Vt[:k, :]
+    with jax.named_scope("rsvd.assemble"):
+        U = Q @ U_b                                    # step 6
+        return U[:, :k], S[:k], Vt[:k, :]
 
 
 @functools.partial(jax.jit, static_argnames=("k", "cfg"))
@@ -364,10 +382,14 @@ def randomized_svd(
 
 def _stabilized_power(A: jax.Array, Y: jax.Array, cfg: RSVDConfig) -> jax.Array:
     for _ in range(cfg.power_iters):
-        Q = qr_mod.orthonormalize(Y, cfg.qr_method)
-        Z = A.T @ Q
-        Qz = qr_mod.orthonormalize(Z, cfg.qr_method)
-        Y = A @ Qz
+        with jax.named_scope("rsvd.orth"):
+            Q = qr_mod.orthonormalize(Y, cfg.qr_method)
+        with jax.named_scope("rsvd.power"):
+            Z = A.T @ Q
+        with jax.named_scope("rsvd.orth"):
+            Qz = qr_mod.orthonormalize(Z, cfg.qr_method)
+        with jax.named_scope("rsvd.power"):
+            Y = A @ Qz
     return Y
 
 

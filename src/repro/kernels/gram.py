@@ -79,6 +79,7 @@ def gram_padded(
         out_shape=jax.ShapeDtypeStruct((s, s), out_dtype),
         scratch_shapes=[pltpu.VMEM((bs, bs), jnp.float32)],
         interpret=interpret,
+        name="gram",
     )(y, y)
     return upper
 

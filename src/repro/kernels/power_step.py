@@ -196,6 +196,7 @@ def sketch_power_padded(
         ],
         compiler_params=_COMPILER_PARAMS,
         interpret=interpret,
+        name="sketch_power",
     )(sd, a)
 
 
@@ -259,4 +260,5 @@ def power_step_padded(
         scratch_shapes=scratch,
         compiler_params=_COMPILER_PARAMS,
         interpret=interpret,
+        name="power_step",
     )(a, x)

@@ -142,6 +142,7 @@ def sketch_matmul_padded(
         out_shape=jax.ShapeDtypeStruct((m, s_padded), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
         interpret=interpret,
+        name="sketch_matmul",
     )(off, sd, a)
 
 
@@ -235,4 +236,5 @@ def sketch_gram_padded(
             pltpu.VMEM((s_padded, s_padded), jnp.float32),
         ],
         interpret=interpret,
+        name="sketch_gram",
     )(off, sd, a)

@@ -134,4 +134,5 @@ def spmm_sketch_padded(
         ),
         out_shape=jax.ShapeDtypeStruct((nrb * bm, s_padded), out_dtype),
         interpret=interpret,
+        name="spmm_sketch",
     )(tilecols.reshape(-1), sd, data)

@@ -89,4 +89,5 @@ def tri_solve_right_padded(
         out_specs=pl.BlockSpec((bm, s), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((m, s), out_dtype),
         interpret=interpret,
+        name="trsm",
     )(y, r, dinv)

@@ -40,6 +40,7 @@ from repro.linalg import pipeline as pipeline_mod
 from repro.linalg import planner as planner_mod
 from repro.linalg import registry as registry_mod
 from repro.linalg import snapshot as snapshot_mod
+from repro.linalg import trace
 from repro.linalg.operators import LinOp, ShardedOp, as_linop, prefetch_panels
 from repro.linalg.planner import Budget, ExecutionPlan
 from repro.linalg.spec import Rank, Spec, as_spec
@@ -157,49 +158,57 @@ def decompose(
     engine state is persisted at panel-group boundaries.  An interrupted
     call re-issued with the same arguments and checkpoint directory
     resumes from the last snapshot, bit-identical to an uninterrupted run;
-    `None` (default) adds zero work and zero HBM traffic."""
-    spec = as_spec(spec)
-    entry = registry_mod.get(kind)
-    op = as_linop(a)
-    if entry.prepare is not None:
-        op = entry.prepare(op)
-    if plan is not None and (plan.kind != kind or plan.spec != spec):
-        raise ValueError(
-            f"pinned plan was built for kind={plan.kind!r} "
-            f"spec={plan.spec.describe() if plan.spec else None}, which does "
-            f"not match the requested kind={kind!r} spec={spec.describe()} — "
-            "re-plan with linalg.plan(a, spec, kind=kind)"
-        )
-    pl = plan if plan is not None else registry_mod.cached_plan(
-        op, spec, budget=budget, overrides=overrides, kind=kind,
-        guard=guard, validate=bool(validate),
-    )
-    pl = _with_guard_overrides(pl, guard, validate, pinned=plan is not None)
-    with snapshot_mod.maybe_scope(checkpoint), \
-            guard_mod.validated(op, pl.validate):
-        if pl.guard.mode != "off":
-            ortho = None
-            if entry.ortho_factor is not None:
-                ortho = lambda res: entry.ortho_factor(res[0])  # noqa: E731
-            result, health = guard_mod.run_guarded(
-                lambda op_, pl_, seed_: entry.execute(op_, spec, pl_, seed_),
-                op, pl, seed, ortho_factor=ortho,
+    `None` (default) adds zero work and zero HBM traffic.
+
+    The call runs under a `linalg.decompose` span whose ``solve`` is the
+    `linalg.solves` count, the identifier its child spans share
+    (linalg/trace.py)."""
+    solve = trace.add("linalg.solves")
+    with trace.span("linalg.decompose", solve=solve):
+        spec = as_spec(spec)
+        entry = registry_mod.get(kind)
+        op = as_linop(a)
+        if entry.prepare is not None:
+            op = entry.prepare(op)
+        if plan is not None and (plan.kind != kind or plan.spec != spec):
+            raise ValueError(
+                f"pinned plan was built for kind={plan.kind!r} "
+                f"spec={plan.spec.describe() if plan.spec else None}, which does "
+                f"not match the requested kind={kind!r} spec={spec.describe()} — "
+                "re-plan with linalg.plan(a, spec, kind=kind)"
             )
-            factors, rank, rank_history, err_history = result
-        else:
-            health = None
-            factors, rank, rank_history, err_history = entry.execute(
-                op, spec, pl, seed)
-    return Decomposition(
-        kind=kind,
-        spec=spec,
-        plan=pl,
-        rank=int(rank),
-        factors=tuple(factors),
-        rank_history=tuple(rank_history),
-        err_history=tuple(err_history),
-        health=health,
-    )
+        pl = plan if plan is not None else registry_mod.cached_plan(
+            op, spec, budget=budget, overrides=overrides, kind=kind,
+            guard=guard, validate=bool(validate),
+        )
+        pl = _with_guard_overrides(pl, guard, validate, pinned=plan is not None)
+
+        def execute(op_, pl_, seed_):
+            with trace.span("linalg.execute"):
+                return entry.execute(op_, spec, pl_, seed_)
+
+        with snapshot_mod.maybe_scope(checkpoint), \
+                guard_mod.validated(op, pl.validate):
+            if pl.guard.mode != "off":
+                ortho = None
+                if entry.ortho_factor is not None:
+                    ortho = lambda res: entry.ortho_factor(res[0])  # noqa: E731
+                result, health = guard_mod.run_guarded(
+                    execute, op, pl, seed, ortho_factor=ortho)
+                factors, rank, rank_history, err_history = result
+            else:
+                health = None
+                factors, rank, rank_history, err_history = execute(op, pl, seed)
+        return Decomposition(
+            kind=kind,
+            spec=spec,
+            plan=pl,
+            rank=int(rank),
+            factors=tuple(factors),
+            rank_history=tuple(rank_history),
+            err_history=tuple(err_history),
+            health=health,
+        )
 
 
 def _with_guard_overrides(pl: ExecutionPlan, guard, validate,
@@ -292,15 +301,16 @@ def _execute_svd_plan(op: LinOp, k: int, pl: ExecutionPlan, seed) -> SVDResult:
 
         A = _dense_array(op)
         seed_arr = jnp.asarray(seed, jnp.uint32)
-        if guard_mod.active_sink() is not None:
-            # guarded run: the probed compiled twin returns the health
-            # scalars as extra jit outputs (the unguarded program and its
-            # cache entry are untouched — guard "off" stays bit-identical)
-            out, probes = rsvd_mod._randomized_svd_dense_probed(
-                A, seed_arr, k, cfg, faults_mod.fingerprint())
-            guard_mod.absorb(probes)
-            return out
-        return rsvd_mod._randomized_svd_dense(A, seed_arr, k, cfg)
+        with trace.span("engine.dense"):
+            if guard_mod.active_sink() is not None:
+                # guarded run: the probed compiled twin returns the health
+                # scalars as extra jit outputs (the unguarded program and its
+                # cache entry are untouched — guard "off" stays bit-identical)
+                out, probes = rsvd_mod._randomized_svd_dense_probed(
+                    A, seed_arr, k, cfg, faults_mod.fingerprint())
+                guard_mod.absorb(probes)
+                return out
+            return rsvd_mod._randomized_svd_dense(A, seed_arr, k, cfg)
     if pl.path == "streamed":
         from repro.core import blocked
 

@@ -39,6 +39,7 @@ except ImportError:  # pragma: no cover - scipy is present in the image
     _scipy_sparse = None
 
 from repro.linalg import pipeline as pipeline_mod
+from repro.linalg import trace
 
 
 class LinOp:
@@ -159,13 +160,16 @@ class DenseOp(LinOp):
         m = self.shape[0]
         b = block_rows or self.block_rows or m
         device_resident = isinstance(self.array, jax.Array)
-        for lo in range(0, m, b):
+        for i, lo in enumerate(range(0, m, b)):
             panel = self.array[lo : min(lo + b, m)]
             # Device-resident arrays slice lazily — re-wrapping the slice in
             # jnp.asarray forced a per-panel copy of data that never left
             # HBM.  Host (numpy) slices keep the explicit host->device move
             # (the HostOp contract; prefetch_panels overlaps it).
-            yield panel if device_resident else jnp.asarray(panel)
+            if not device_resident:
+                with trace.span("pipeline.panel"):
+                    panel = pipeline_mod.put_panel(panel, i)
+            yield panel
 
 
 class HostOp(DenseOp):

@@ -52,6 +52,14 @@ the fault-injection hooks (linalg/faults.py), the guard's per-panel
 finiteness probe, and the `validate=` screen (raising a ValueError that
 names the offending panel) all live there, and all cost nothing when
 inactive.
+
+Tracing (linalg/trace.py): every host panel reaches the device through
+`put_panel`, under a `pipeline.put` span, counted in `pipeline.panels` and
+`pipeline.h2d_bytes`.  The production of each host panel (slice, staging,
+put, probes) runs under a `pipeline.panel` span, on the ring, the
+synchronous walk and its fallback alike, and a wait for a staging slot's
+earlier transfer under `pipeline.slot_wait`.  Device-resident panels are
+lazy slices: nothing is staged, and `lookahead` adds no span.
 """
 from __future__ import annotations
 
@@ -67,6 +75,7 @@ import numpy as np
 
 from repro.linalg import faults as faults_mod
 from repro.linalg import guard as guard_mod
+from repro.linalg import trace
 
 #: bounded retry of a failed staging transfer, with exponential backoff
 TRANSFER_RETRIES = 3
@@ -205,9 +214,10 @@ def _await_in_flight(in_flight: List[Optional[jax.Array]]) -> None:
     """Fence every in-flight staged transfer (slot-reuse + early-exit
     safety: called from the stream's ``finally`` so a consumer raising or
     abandoning mid-stream can never leave a DMA reading ring memory)."""
-    for dev in in_flight:
-        if dev is not None:
-            dev.block_until_ready()
+    with trace.span("pipeline.slot_wait"):
+        for dev in in_flight:
+            if dev is not None:
+                dev.block_until_ready()
 
 
 def _put_with_retry(buf, idx: int) -> jax.Array:
@@ -223,9 +233,34 @@ def _put_with_retry(buf, idx: int) -> jax.Array:
             if attempt == TRANSFER_RETRIES:
                 raise _StagingFailed(idx) from None
             guard_mod.note_transfer_retry()
+            trace.add("pipeline.transfer_retries")
             time.sleep(delay)
             delay *= 2
     raise _StagingFailed(idx)  # unreachable
+
+
+def put_panel(host: np.ndarray, idx: int, retry: bool = False) -> jax.Array:
+    """Move one host panel to the device: every walk's host panels come
+    through here, under a `pipeline.put` span, counted in `pipeline.panels`
+    and `pipeline.h2d_bytes` (the bytes that land on the device, staging
+    padding included).  ``retry`` (the staged ring) puts with bounded
+    retry-with-backoff and may raise `_StagingFailed`; the synchronous walks
+    put plainly, as `jnp.asarray` always has."""
+    with trace.span("pipeline.put"):
+        dev = _put_with_retry(host, idx) if retry else jnp.asarray(host)
+    trace.add("pipeline.panels")
+    trace.add("pipeline.h2d_bytes", dev.nbytes)
+    return dev
+
+
+def _produce_sync(array, bounds, start: int = 0) -> Iterator[jax.Array]:
+    """The synchronous walk over ``bounds[start:]``: each host slice put
+    and probed under its `pipeline.panel` span."""
+    for i in range(start, len(bounds)):
+        lo, hi = bounds[i]
+        with trace.span("pipeline.panel"):
+            panel = _panel_probe(i, put_panel(array[lo:hi], i), rows=(lo, hi))
+        yield panel
 
 
 def stream_host_panels(
@@ -260,8 +295,7 @@ def stream_host_panels(
         return
     depth = max(1, min(int(depth), len(bounds)))
     if depth == 1:
-        for i, (lo, hi) in enumerate(bounds):
-            yield _panel_probe(i, jnp.asarray(array[lo:hi]), rows=(lo, hi))
+        yield from _produce_sync(array, bounds)
         return
     block = max(hi - lo for lo, hi in bounds)
     n = array.shape[1]
@@ -279,20 +313,23 @@ def stream_host_panels(
         lo, hi = bounds[idx]
         rows = hi - lo
         slot = idx % depth
-        prev = in_flight[slot]
-        if prev is not None:
-            prev.block_until_ready()  # DMA/copy out of this slot must be done
-        buf = ring[slot]
-        buf[:rows] = array[lo:hi]
-        if rows < block:
-            buf[rows:] = 0  # uniform transfer shape, jit-stable
-        faults_mod.corrupt_staged(idx, buf[:rows])
-        dev = _put_with_retry(buf, idx)
-        if chase_copy:
-            dev = _device_copy(dev)
-        in_flight[slot] = dev
-        panel = dev if rows == block else dev[:rows]
-        return _panel_probe(idx, panel, rows=(lo, hi))
+        with trace.span("pipeline.panel"):
+            prev = in_flight[slot]
+            if prev is not None:
+                # DMA/copy out of this slot must be done
+                with trace.span("pipeline.slot_wait"):
+                    prev.block_until_ready()
+            buf = ring[slot]
+            buf[:rows] = array[lo:hi]
+            if rows < block:
+                buf[rows:] = 0  # uniform transfer shape, jit-stable
+            faults_mod.corrupt_staged(idx, buf[:rows])
+            dev = put_panel(buf, idx, retry=True)
+            if chase_copy:
+                dev = _device_copy(dev)
+            in_flight[slot] = dev
+            panel = dev if rows == block else dev[:rows]
+            return _panel_probe(idx, panel, rows=(lo, hi))
 
     fallback_from: Optional[int] = None
     pending: collections.deque = collections.deque()
@@ -319,9 +356,7 @@ def stream_host_panels(
             # the link stayed down through the retry budget: finish the walk
             # synchronously (same values, no overlap) instead of failing
             guard_mod.note_transfer_degraded()
-            for i in range(fallback_from, len(bounds)):
-                lo, hi = bounds[i]
-                yield _panel_probe(i, jnp.asarray(array[lo:hi]), rows=(lo, hi))
+            yield from _produce_sync(array, bounds, fallback_from)
     finally:
         _await_in_flight(in_flight)
 

@@ -39,6 +39,7 @@ import numpy as np
 
 from repro.core import qr as qr_mod
 from repro.core.rsvd import _small_svd
+from repro.linalg import trace
 from repro.linalg.spec import Rank, Spec
 
 #: (factors, rank, rank_history, err_history)
@@ -109,7 +110,7 @@ PLAN_CACHE_SIZE = 256
 
 _plan_cache: "collections.OrderedDict" = collections.OrderedDict()
 _plan_cache_lock = threading.Lock()  # decompose() is called from service threads
-_plan_cache_stats = {"hits": 0, "misses": 0, "bypasses": 0}
+_PLAN_CACHE_OUTCOMES = ("hits", "misses", "bypasses")
 
 
 def _op_fingerprint(op):
@@ -154,47 +155,49 @@ def cached_plan(op, spec, budget=None, overrides=None, kind: str = "svd",
     inputs.  Semantically transparent: a hit returns the SAME frozen
     ExecutionPlan a fresh plan() call would build (plans carry no data), and
     un-fingerprintable sources fall through to planner.plan untouched."""
-    from repro.linalg import guard as guard_mod
-    from repro.linalg import planner as planner_mod
-    from repro.linalg import spec as spec_mod
+    with trace.span("linalg.plan"):
+        from repro.linalg import guard as guard_mod
+        from repro.linalg import planner as planner_mod
+        from repro.linalg import spec as spec_mod
 
-    spec = spec_mod.as_spec(spec)
-    guard = guard_mod.as_guard(guard)
-    token = _op_fingerprint(op)
-    if token is None:
+        spec = spec_mod.as_spec(spec)
+        guard = guard_mod.as_guard(guard)
+        token = _op_fingerprint(op)
+        if token is None:
+            trace.add("plan_cache.bypasses")
+            return planner_mod.plan(op, spec, budget=budget, overrides=overrides,
+                                    kind=kind, nnz=nnz, guard=guard,
+                                    validate=validate)
+        key = (token, spec, kind, budget, overrides, nnz, guard, bool(validate),
+               jax.default_backend())
         with _plan_cache_lock:
-            _plan_cache_stats["bypasses"] += 1
-        return planner_mod.plan(op, spec, budget=budget, overrides=overrides,
-                                kind=kind, nnz=nnz, guard=guard,
-                                validate=validate)
-    key = (token, spec, kind, budget, overrides, nnz, guard, bool(validate),
-           jax.default_backend())
-    with _plan_cache_lock:
-        pl = _plan_cache.get(key)
-        if pl is not None:
-            _plan_cache.move_to_end(key)
-            _plan_cache_stats["hits"] += 1
-            return pl
-        _plan_cache_stats["misses"] += 1
-    pl = planner_mod.plan(op, spec, budget=budget, overrides=overrides,
-                          kind=kind, nnz=nnz, guard=guard, validate=validate)
-    with _plan_cache_lock:
-        _plan_cache[key] = pl
-        while len(_plan_cache) > PLAN_CACHE_SIZE:
-            _plan_cache.popitem(last=False)
-    return pl
+            pl = _plan_cache.get(key)
+            if pl is not None:
+                _plan_cache.move_to_end(key)
+                trace.add("plan_cache.hits")
+                return pl
+            trace.add("plan_cache.misses")
+        pl = planner_mod.plan(op, spec, budget=budget, overrides=overrides,
+                              kind=kind, nnz=nnz, guard=guard, validate=validate)
+        with _plan_cache_lock:
+            _plan_cache[key] = pl
+            while len(_plan_cache) > PLAN_CACHE_SIZE:
+                _plan_cache.popitem(last=False)
+        return pl
 
 
 def plan_cache_stats() -> Dict[str, int]:
+    """hits / misses / bypasses of the plan cache (the `plan_cache.*`
+    counters of linalg/trace.py) and its present size."""
+    stats = {k: trace.count(f"plan_cache.{k}") for k in _PLAN_CACHE_OUTCOMES}
     with _plan_cache_lock:
-        return dict(_plan_cache_stats, size=len(_plan_cache))
+        return dict(stats, size=len(_plan_cache))
 
 
 def clear_plan_cache() -> None:
     with _plan_cache_lock:
         _plan_cache.clear()
-        for k in _plan_cache_stats:
-            _plan_cache_stats[k] = 0
+    trace.reset("plan_cache.")
 
 
 # ---------------------------------------------------------------------------

@@ -7,8 +7,8 @@ slices arrived in.  ExecutionPlans are frozen/hashable, so the plan itself
 keys the cache; a hit returns a callable whose underlying jit trace already
 exists, making the steady-state hot path re-trace-free.
 
-Trace accounting: `core.blocked._TRACE_COUNTS` is incremented INSIDE the
-batched body, so it ticks at trace time only.  `trace_count(plan)` maps a
+Trace accounting: the `jit.traces.<key>` counter (linalg/trace.py) is
+incremented INSIDE the batched body, so it ticks at trace time only.  `trace_count(plan)` maps a
 plan to its body-level trace key (same orientation swap and config
 normalization `svd_batched` applies) — tests and the bench assert at most
 one trace per distinct plan across N same-plan requests.
@@ -27,7 +27,7 @@ from repro.linalg.planner import ExecutionPlan
 
 
 def _trace_key_for(pl: ExecutionPlan):
-    """The `blocked._TRACE_COUNTS` key this plan's batches trace under.
+    """The `blocked._trace_key` this plan's batches trace under.
 
     Mirrors `svd_batched` exactly: wide stacks are transposed to the tall
     orientation before the jit boundary (pl.m/pl.n are already recorded

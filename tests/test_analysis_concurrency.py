@@ -12,7 +12,7 @@ import pytest
 from repro import linalg
 from repro.core import blocked
 from repro.kernels import autotune
-from repro.linalg import faults, registry
+from repro.linalg import faults, registry, trace
 from repro.serve.decomp import cache as serve_cache
 
 pytestmark = pytest.mark.analysis
@@ -126,8 +126,7 @@ def test_trace_counter_is_exact_under_contention():
     # An unlocked Counter drops increments under contention; the locked one
     # must account for every single trace.
     assert blocked.trace_count(key) - before == N_THREADS * iters
-    with blocked._trace_counts_lock:
-        blocked._TRACE_COUNTS.pop(key, None)
+    trace.reset(blocked._trace_counter(key))
 
 
 def test_service_traces_once_per_plan_under_thread_storm():

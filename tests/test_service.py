@@ -7,7 +7,7 @@ Pins the subsystem's contracts:
     batch the coalescer formed, and under arrival-order permutation
     (property test);
   * compiled-executable cache: at most ONE trace per distinct plan across
-    N same-plan requests (asserted on `blocked._TRACE_COUNTS`);
+    N same-plan requests (asserted on `blocked.trace_count`);
   * two-lane scheduling starvation bound: a 65536 x 4096 out-of-core job
     concurrent with >= 100 small requests never makes an admitted request
     wait more than K big-job slices;
