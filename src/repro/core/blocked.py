@@ -250,12 +250,115 @@ def _blocked_cholesky_qr3(Y_panels: Sequence[jax.Array], G1: jax.Array | None = 
     return Q, R21 @ R0
 
 
-def _panel_orthonormalizer(cfg: RSVDConfig):
-    """The panel-split orthonormalizer for this config: CQR2 unless the
-    plan (or the guard ladder, via a replaced plan) asks for the shifted
-    CQR3.  Householder has no row-panel-split form — the ladder skips it
-    for streamed plans and goes straight to the f64 recompute."""
-    return _blocked_cholesky_qr3 if cfg.qr_method == "cqr3" else _blocked_cholesky_qr2
+# ---------------------------------------------------------------------------
+# The orthonormalization steps between two passes over A, each one compiled
+# program.  Run op by op, a step is some forty tiny dispatches (the Gram's
+# floor shift, the Cholesky, the transposes, the TRSM and its casts, R2 R1),
+# and the host's dispatch cost, not the arithmetic, sets its time.  Whatever
+# the bodies read at trace time is a static argument: the CholeskyQR variant
+# and the kernel backend (a thread-local, as for `_gram_accum`).  Guarded
+# runs call the probed twins, which trace the same body under an open guard
+# sink and return its probes as extra outputs (the `_batched_tall_probed`
+# contract, `fault_key` included); the plain programs never carry a probe.
+# ---------------------------------------------------------------------------
+
+def _orth_panels_impl(Y_panels, G1, cqr3: bool, backend):
+    """Panel CholeskyQR2 (shifted CQR3 when the plan, or the guard ladder
+    via a replaced plan, asks for it) of a row-panel-split Y; returns
+    (Q_panels, R).  Householder has no row-panel-split form: the ladder
+    skips it for streamed plans and goes straight to the f64 recompute.
+
+    Where a CQR2 Cholesky breaks down, the step returns the shifted CQR3
+    of the same panels instead.  The floor shift leaves a Gram of kappa(Y)
+    past eps^-1/2 with condition near 1/eps, and a TPU v5e's f32 Cholesky
+    fails on a few such Grams (one solve in 1800 at 2000 x 2000, 1/i^2
+    spectrum, k = 200).  A guarded run still records the breakdown."""
+    with qr_mod.kernel_backend(backend):
+        if cqr3:
+            return _blocked_cholesky_qr3(Y_panels, G1)
+        Q, R = _blocked_cholesky_qr2(Y_panels, G1)
+        return jax.lax.cond(
+            jnp.all(jnp.isfinite(R)), lambda: (Q, R),
+            lambda: _unprobed(_blocked_cholesky_qr3, Y_panels, G1))
+
+
+def _unprobed(fn, *args):
+    """fn(*args) with an active guard sink hidden behind a throwaway one:
+    a probe recorded inside a `lax.cond` branch may not leave it."""
+    import sys
+
+    g = sys.modules.get("repro.linalg.guard")
+    if g is None or g.active_sink() is None:
+        return fn(*args)
+    with g.collecting():
+        return fn(*args)
+
+
+def _orthonormalize_impl(Z, method, backend):
+    """orthonormalize(Z): the CholeskyQR2/3 of Z as a one-panel
+    `_orth_panels_impl` (op for op `qr.orthonormalize`, with its breakdown
+    fallback), other methods through `qr.orthonormalize`."""
+    if method in ("cqr2", "cqr3"):
+        return _orth_panels_impl([Z], None, method == "cqr3", backend)[0][0]
+    with qr_mod.kernel_backend(backend):
+        return qr_mod.orthonormalize(Z, method)
+
+
+def _orth_trace_key(program: str, arrays, **static):
+    """The `_note_trace` key of one orth program: its shapes, dtype and
+    static arguments, so `trace_count` shows a new seed never retraces."""
+    shapes = tuple(tuple(a.shape) for a in arrays if a is not None)
+    return (f"engine.orth.{program}", shapes, jnp.dtype(arrays[0].dtype).name,
+            tuple(sorted(static.items())))
+
+
+@functools.partial(jax.jit, static_argnames=("cqr3", "backend"))
+def _orth_panels(Y_panels, G1, *, cqr3: bool, backend):
+    _note_trace(_orth_trace_key("panels", [*Y_panels, G1], g1=G1 is not None,
+                                cqr3=cqr3, backend=backend))
+    return _orth_panels_impl(Y_panels, G1, cqr3, backend)
+
+
+@functools.partial(jax.jit, static_argnames=("method", "backend"))
+def _orthonormalize(Z, *, method, backend):
+    _note_trace(_orth_trace_key("orthonormalize", [Z], method=method,
+                                backend=backend))
+    return _orthonormalize_impl(Z, method, backend)
+
+
+def _probed(impl):
+    """The guarded twin of an orth program (see the section comment)."""
+
+    def body(*args, fault_key, **static):
+        del fault_key
+        from repro.linalg import guard as guard_mod
+
+        with guard_mod.collecting() as sink:
+            out = impl(*args, **static)
+        return out, sink.traced()
+
+    return body
+
+
+_orth_panels_probed = jax.jit(_probed(_orth_panels_impl),
+                              static_argnames=("cqr3", "backend", "fault_key"))
+_orthonormalize_probed = jax.jit(_probed(_orthonormalize_impl),
+                                 static_argnames=("method", "backend",
+                                                  "fault_key"))
+
+
+def _orth_step(plain, probed, *args, **static):
+    """Run one compiled orth step: the probed twin under an active guard
+    sink (its probes folded into that sink), the plain program otherwise.
+    Counts `engine.orth.calls`."""
+    from repro.linalg import faults as faults_mod, guard as guard_mod, trace
+
+    trace.add("engine.orth.calls")
+    if guard_mod.active_sink() is not None:
+        out, probes = probed(*args, fault_key=faults_mod.fingerprint(), **static)
+        guard_mod.absorb(probes)
+        return out
+    return plain(*args, **static)
 
 
 # ---------------------------------------------------------------------------
@@ -314,7 +417,7 @@ def svd_streamed(
 # Gram, Z/B accumulators, cursor) at any panel-group boundary and a restored
 # run continues the walk from `bounds[cursor:]`.  Everything NOT saved (the
 # CholeskyQR bases Q/Qz, Omega slabs) is recomputed on restore from saved
-# bytes through the same ops — resumed factors are bit-identical to the
+# bytes through the same programs — resumed factors are bit-identical to the
 # uninterrupted run.  With no snapshot scope active each boundary is one
 # sys.modules probe; the arithmetic and its order are EXACTLY the
 # pre-machine body's (tests/test_blocked.py pins fixed-seed bytes).
@@ -417,7 +520,11 @@ def _blocked_body(panels, k: int, s: int, cfg: RSVDConfig, seed, dtype,
 
     st = (_stream_resume(token) if token is not None else None) \
         or _StreamState(token or "")
-    _panel_orth = _panel_orthonormalizer(cfg)
+    backend = qr_mod.active_kernel_backend()
+
+    def panel_orth(Y, G1):
+        return _orth_step(_orth_panels, _orth_panels_probed, Y, G1,
+                          cqr3=cfg.qr_method == "cqr3", backend=backend)
 
     # Step 1-2a: per-panel sketch.  Omega is n x s regenerated per panel from
     # the counter RNG — identical for every panel, no broadcast state.  The
@@ -458,9 +565,9 @@ def _blocked_body(panels, k: int, s: int, cfg: RSVDConfig, seed, dtype,
                 src = st.Y
             else:
                 # recomputed (not snapshotted) on resume: a deterministic
-                # function of the saved Y panels + Gram, same ops
+                # function of the saved Y panels + Gram, same program
                 with span("engine.orth"):
-                    src, _ = _panel_orth(st.Y, st.G1)
+                    src, _ = panel_orth(st.Y, st.G1)
             with span("engine.power_z"):
                 for Ap, Xp in zip(panels(st.cursor), src[st.cursor:]):
                     st.Z = Ap.T @ Xp if st.Z is None else _accum_xty(st.Z, Ap, Xp)
@@ -470,8 +577,10 @@ def _blocked_body(panels, k: int, s: int, cfg: RSVDConfig, seed, dtype,
             if cfg.power_scheme == "plain":
                 mult = st.Z
             else:
-                with span("engine.orth"):
-                    mult = qr_mod.orthonormalize(st.Z, cfg.qr_method)  # n x s, fits
+                with span("engine.orth"):  # n x s, fits
+                    mult = _orth_step(_orthonormalize, _orthonormalize_probed,
+                                      st.Z, method=cfg.qr_method,
+                                      backend=backend)
             with span("engine.power_y"):
                 for Ap in panels(st.cursor):
                     st.Y.append(Ap @ mult)
@@ -485,7 +594,7 @@ def _blocked_body(panels, k: int, s: int, cfg: RSVDConfig, seed, dtype,
     # Steps 3-4: orthonormal range basis (panel-split; recomputed from the
     # saved Y/G1 on resume), then B = Q^T A through the s x n accumulator.
     with span("engine.orth"):
-        Q, _ = _panel_orth(st.Y, st.G1)
+        Q, _ = panel_orth(st.Y, st.G1)
     with span("engine.project"):
         for Ap, Qp in zip(panels(st.cursor), Q[st.cursor:]):
             st.B = Qp.T @ Ap if st.B is None else _accum_xty(st.B, Qp, Ap)

@@ -29,7 +29,10 @@ concurrently); `counters()` is a snapshot:
     linalg.solves                  calls of `decompose`
     plan_cache.hits / .misses / .bypasses
                                    the LRU plan cache (registry.plan_cache_stats)
-    jit.traces.<key>               traces of the batched body (blocked.trace_count)
+    jit.traces.<key>               traces of the batched body and of the
+                                   streamed engine's orth programs
+                                   (blocked.trace_count)
+    engine.orth.calls              compiled orth steps the streamed engine ran
     pipeline.panels                host panels put on the device
     pipeline.h2d_bytes             bytes those puts moved, staging padding included
     pipeline.transfer_retries      puts retried after a transfer error

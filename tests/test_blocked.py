@@ -8,7 +8,10 @@ Covers the DESIGN.md §"Blocked & batched execution" contracts:
   * the batched vmap path equals a per-slice Python loop, in both the tall
     and the wide (orientation-swap) layouts;
   * the streamed sketch accumulation (panel-offset counter RNG) equals the
-    monolithic sketch.
+    monolithic sketch;
+  * each orthonormalization step between two passes is one compiled
+    program that matches its eager composition, traced once per shape and
+    called 2q + 1 times a solve.
 """
 import numpy as np
 import jax.numpy as jnp
@@ -16,6 +19,8 @@ import pytest
 
 from repro import linalg
 from repro.core import RSVDConfig, low_rank_error, streamed_sketch, truncation_error
+from repro.core import blocked
+from repro.core import qr as qr_mod
 from repro.core.spectra import make_test_matrix
 from repro.kernels import ref
 
@@ -158,3 +163,80 @@ def test_streamed_sketch_matches_monolithic(kind, fused):
     got = streamed_sketch(A, 17, seed=3, kind=kind, block_cols=40, fused=fused)
     want = ref.sketch_matmul_ref(A, 17, seed=3, kind=kind)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-4, rtol=2e-4)
+
+
+# ---------------------------------------------------------------------------
+# (e) the compiled orthonormalization steps of the streamed engine
+# ---------------------------------------------------------------------------
+
+def _check_orth(Q, R, Y, Q_eager):
+    """Q orthonormal, QR = Y, and Q within 1e-5 of the eager composition's
+    (two programs: no bits pinned between them)."""
+    Q, R, Y = (np.asarray(x, np.float64) for x in (Q, R, Y))
+    assert np.linalg.norm(Q.T @ Q - np.eye(Q.shape[1])) <= 1e-4
+    assert np.linalg.norm(Q @ R - Y) / np.linalg.norm(Y) <= 1e-5
+    np.testing.assert_allclose(Q, np.asarray(Q_eager), rtol=0, atol=1e-5)
+
+
+@pytest.mark.parametrize("cqr3", [False, True], ids=["cqr2", "cqr3"])
+@pytest.mark.parametrize("with_g1", [False, True], ids=["no_g1", "g1"])
+@pytest.mark.parametrize("rows", [(96,), (40, 40, 17)], ids=["one", "ragged3"])
+def test_compiled_panel_orth_matches_eager(rows, with_g1, cqr3):
+    rng = np.random.default_rng(sum(rows))
+    Y = rng.standard_normal((sum(rows), 12)).astype(np.float32)
+    edges = np.cumsum((0,) + rows)
+    panels = [jnp.asarray(Y[lo:hi]) for lo, hi in zip(edges[:-1], edges[1:])]
+    G1 = jnp.asarray(Y.T @ Y) if with_g1 else None
+    Q, R = blocked._orth_panels(panels, G1, cqr3=cqr3, backend="jnp")
+    Q_eager, _ = blocked._orth_panels_impl(panels, G1, cqr3, "jnp")
+    assert [q.shape for q in Q] == [p.shape for p in panels]
+    _check_orth(jnp.concatenate(Q), R, Y, jnp.concatenate(Q_eager))
+
+
+def test_compiled_panel_orth_falls_back_to_cqr3_on_breakdown():
+    """A CQR2 Cholesky that breaks down (here forced once, in a guarded
+    trace) yields the shifted CQR3 of the same panels, and the probe still
+    reports the breakdown."""
+    from repro.linalg import faults, guard
+
+    Y = np.random.default_rng(9).standard_normal((96, 12)).astype(np.float32)
+    panels = [jnp.asarray(Y[:50]), jnp.asarray(Y[50:])]
+    with guard.collecting(), faults.inject("cholesky_breakdown", times=1):
+        (Q, R), probes = blocked._orth_panels_probed(
+            panels, None, cqr3=False, backend="jnp",
+            fault_key=faults.fingerprint())
+    assert bool(probes["breakdown"])
+    Q3, _ = blocked._orth_panels_impl(panels, None, True, "jnp")
+    _check_orth(jnp.concatenate(Q), R, Y, jnp.concatenate(Q3))
+
+
+@pytest.mark.parametrize("method", ["cqr2", "cqr3"])
+def test_compiled_orthonormalize_matches_eager(method):
+    Z = np.random.default_rng(5).standard_normal((80, 12)).astype(np.float32)
+    Q = blocked._orthonormalize(jnp.asarray(Z), method=method, backend="jnp")
+    Q_eager = qr_mod.orthonormalize(jnp.asarray(Z), method)
+    _check_orth(Q, Q.T @ Z, Z, Q_eager)
+
+
+def test_orth_programs_traced_once_and_called_per_step():
+    """Three solves of one HostOp shape under new seeds: each orth program
+    is traced exactly once, and `engine.orth.calls` moves by 2q + 1 (the
+    panel CQR2 before each power_z pass, orthonormalize(Z) before each
+    power_y pass, the final panel CQR2) per solve."""
+    from repro.linalg import trace
+
+    A = np.random.default_rng(3).standard_normal((333, 40)).astype(np.float32)
+    op = linalg.HostOp(A, block_rows=128)  # a shape no other test streams
+    pl = linalg.plan(op, linalg.Rank(6))
+    assert pl.power_scheme != "plain"
+    before = trace.counters()
+    for seed in (11, 12, 13):
+        linalg.decompose(op, linalg.Rank(6), seed=seed)
+        calls = trace.count("engine.orth.calls") - before.get("engine.orth.calls", 0)
+        assert calls == (2 * pl.power_iters + 1) * (seed - 10)
+    traced = {name: n for name, n in trace.counters().items()
+              if name.startswith("jit.traces.('engine.orth.")
+              and n != before.get(name, 0)}
+    assert {name.split("'")[1] for name in traced} == {
+        "engine.orth.panels", "engine.orth.orthonormalize"}
+    assert set(traced.values()) == {1}

@@ -161,6 +161,16 @@ class TestReportMode:
         assert len(d.health.attempts) == 1
         assert d.health.final.breakdown
 
+    def test_streamed_breakdown_flagged(self):
+        # the fault fires inside the streamed engine's compiled orth step,
+        # traced under the probed twin's sink and absorbed into the report
+        op = linalg.HostOp(_gauss(224, 48, seed=4), block_rows=64)
+        with faults.inject("cholesky_breakdown"):
+            d = linalg.decompose(op, 8, seed=5, guard="report")
+        assert not d.health.ok
+        assert len(d.health.attempts) == 1
+        assert d.health.final.breakdown
+
 
 # ---------------------------------------------------------------------------
 # retry mode: the escalation ladder (empirical rung pins)
@@ -247,6 +257,18 @@ class TestRetryLadder:
                 guard=linalg.GuardPolicy(mode="retry", max_retries=1))
         assert not d.health.ok
         assert len(d.health.attempts) == 2  # first attempt + one escalation
+
+    def test_streamed_breakdown_climbs_to_cqr3(self):
+        # one forced breakdown (the first compiled orth step of the cqr2
+        # rung); the probed cqr3 program then runs clean and verifies
+        op = linalg.HostOp(_gauss(224, 48, seed=6), block_rows=64)
+        with faults.inject("cholesky_breakdown", times=1):
+            d = linalg.decompose(op, 8, seed=5, guard="retry")
+        h = d.health
+        assert h.ok and h.rung_used == "cqr3"
+        assert tuple(a.rung for a in h.attempts) == ("cqr2", "cqr3")
+        assert h.attempts[0].breakdown and not h.final.breakdown
+        assert h.final.ortho_fro <= 1e-5
 
     def test_ladder_exhausted_returns_last_flagged(self):
         A = _gauss(256, 64, seed=1)
