@@ -11,8 +11,9 @@ share of idle time under each program span, the program's counters over the wind
 and the numbers a per-layer metric would read from them.
 
 Names.  An idle gap is named by the benchmark's span covering most of it
-(`call`, `wait`, `next_source`, as trace_reduce.py names it), then by the
-innermost program span (linalg/trace.py) that covers at least half of it:
+(`call`, `wait`, `next_source`, `submit`, `sleep`, as trace_reduce.py
+names it), then by the innermost program span (linalg/trace.py) that
+covers at least half of it:
 `call/pipeline.put`.  A device op is named by its innermost `rsvd.*` scope
 (core/rsvd.py), read from the `tf_op` stat that a TPU trace gives each op's
 metadata (or, for a `while` that has none, shared by the ops nested in
@@ -33,7 +34,6 @@ from __future__ import annotations
 import bisect
 import json
 import sys
-import time
 from collections import defaultdict
 from pathlib import Path
 
@@ -334,11 +334,9 @@ def main(argv=None) -> int:
 
     import jax
 
-    import loadgen
     import run
     import sources
     import work
-    from repro import linalg
 
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--workload", required=True)
@@ -351,11 +349,12 @@ def main(argv=None) -> int:
     run.enable_compile_cache(run.ROOT)
     device = jax.devices()[0]
     pool = sources.Pool(cfg, traffic, args.seed, device)
-    k, calls = cfg["rank"], 0
-    for _ in range(traffic["warmup_calls"]):
-        member, seed = loadgen.call(traffic, args.seed, calls)
-        run.solve(linalg, pool.members[member], k, seed)
-        calls += 1
+    if traffic["loop"] == "open":
+        import openloop
+
+        loop = openloop.OpenLoop(pool, cfg, traffic, args.seed)
+    else:
+        loop = run.ClosedLoop(pool, cfg, traffic, args.seed)
 
     from repro.linalg import trace
 
@@ -366,16 +365,11 @@ def main(argv=None) -> int:
     jax.profiler.start_trace(str(log_dir), profiler_options=opts)
     before = trace.counters()
     try:
-        t0 = time.perf_counter()
-        with jax.profiler.TraceAnnotation(tr.WINDOW_SPAN):
-            while time.perf_counter() - t0 < args.seconds:
-                with jax.profiler.TraceAnnotation("next_source"):
-                    member, seed = loadgen.call(traffic, args.seed, calls)
-                    calls += 1
-                run.solve(linalg, pool.members[member], k, seed)
+        loop.window(args.seconds)
     finally:
         after = trace.counters()
         jax.profiler.stop_trace()
+        loop.close()
     xplane = tr.find_xplane(log_dir)
     out = reduce_trace(xplane) or {}
     delta = {n: v - before.get(n, 0) for n, v in after.items()
@@ -383,6 +377,7 @@ def main(argv=None) -> int:
     solves = delta.get("linalg.solves")
     pass_bytes = work.required_work(cfg)["bytes"] // (cfg["power_iters"] + 1)
     out.update(device=device.device_kind, xplane=str(xplane), counters=delta,
+               attempted=loop.attempted,
                h2d_passes=(delta.get("pipeline.h2d_bytes", 0) / solves / pass_bytes
                            if solves else None))
     print(json.dumps(out), flush=True)

@@ -5,11 +5,12 @@
         [--precision bf16] [--seconds 2]
 
 Runs the cell as bench/run.py does, once per seed and all in one process,
-with `repro.linalg.decompose` replaced by `reference.decompose`: the plain
-reference at one matmul precision, judged by the harness's own comparison
-under the cell's committed limits.  At "bf16", one bf16 pass, the precision
-below the float32 that the configuration states, every run must come out
-not correct; at "highest" the reference must pass; "high" is read for the
+with the program's entry (`repro.linalg.decompose`, or for an open loop
+`DecompositionService`) replaced by the plain reference at one matmul
+precision, judged by the harness's own comparison under the cell's
+committed limits.  At "bf16", one bf16 pass, the precision below the
+float32 that the configuration states, every run must come out not
+correct; at "highest" the reference must pass; "high" is read for the
 record.  Each run prints its result line, the compared numbers under
 "check".  Benchmark runs never run this.
 """
@@ -24,14 +25,21 @@ sys.path.insert(0, str(BENCH_DIR))
 
 
 def plant(workload: str, precision: str, root: Path) -> None:
-    """Put the reference at `precision` in place of the program's entry."""
+    """Put the reference at `precision` in place of the program's entry:
+    `linalg.decompose` for a closed loop, `DecompositionService` for an
+    open one."""
     import reference
     import run
     from repro import linalg
+    from repro.serve import decomp
 
-    cfg = run.load_cell(root, workload)["config"]
-    linalg.decompose = reference.decompose(precision, cfg["oversample"],
-                                           cfg["power_iters"])
+    spec = run.load_cell(root, workload)
+    cfg = spec["config"]
+    args = (precision, cfg["oversample"], cfg["power_iters"])
+    if spec["traffic"]["loop"] == "open":
+        decomp.DecompositionService = reference.service(*args)
+    else:
+        linalg.decompose = reference.decompose(*args)
 
 
 def main(argv=None) -> int:

@@ -19,8 +19,8 @@ iterations re-orthonormalized by Householder QR, LAPACK-style SVD of B) in
 plain jax.numpy.  Put in the program's place with its products at one bf16
 pass ("bf16"), the precision below the float32 that the configurations
 state, it is the control; "high" (three bf16 passes on a TPU) is kept for
-the record.  `decompose` puts it in the program's place, behind the
-program's own call signature.
+the record.  `decompose` and `service` put it in the program's place,
+behind the program's own call signatures.
 
 This module imports nothing of the program and takes nothing it made.
 """
@@ -154,3 +154,36 @@ def decompose(precision: str, oversample: int, power_iters: int):
         return Decomposition(factors)
 
     return call
+
+
+def service(precision: str, oversample: int, power_iters: int):
+    """A stand-in for `repro.serve.decomp.DecompositionService` whose
+    `submit` runs `rsvd` at `precision` and returns a future resolved with
+    its ready factors: what the control plants in an open-loop run."""
+    from concurrent.futures import Future
+
+    call = decompose(precision, oversample, power_iters)
+
+    class Metrics:
+        @staticmethod
+        def records() -> list:
+            return []
+
+    class Service:
+        def __init__(self, *, max_batch: int = 8):
+            self.metrics = Metrics()
+
+        def submit(self, a, spec, seed=0, **_):
+            dec = call(a, spec, seed=seed)
+            jax.block_until_ready(dec.factors)
+            fut = Future()
+            fut.set_result(dec)
+            return fut
+
+        def flush(self) -> None:
+            pass
+
+        def close(self, timeout=None) -> None:
+            pass
+
+    return Service

@@ -7,8 +7,10 @@ A cell is an entry of `workloads` in BENCHMARK.json: a configuration
 (bench/configs/, the matrix and the solve) under a traffic mix
 (bench/traffic/<name>.json, read by loadgen.py).  The run builds the
 cell's pool of matrices on the device from --seed, warms up every program
-the window runs (set-up), then calls `repro.linalg.decompose` in a closed
-loop for --seconds, each call ended by `block_until_ready` on its factors.
+the window runs (set-up), then measures for --seconds: a closed loop calls
+`repro.linalg.decompose`, each call ended by `block_until_ready` on its
+factors (ClosedLoop, below); an open loop submits to
+`DecompositionService` on a fixed schedule and drains (openloop.py).
 
 With --trace 0 the metrics are the cell's end-to-end metrics; with
 --trace 1 the window runs under the profiler and the metrics are the
@@ -43,6 +45,9 @@ BENCH_DIR = Path(__file__).resolve().parent
 ROOT = BENCH_DIR.parent
 sys.path.insert(0, str(BENCH_DIR))
 sys.path.insert(1, str(ROOT / "src"))
+
+import loadgen  # noqa: E402
+import trace_reduce  # noqa: E402
 
 #: past this many seconds the run dumps every thread's stack and exits
 #: non-zero, inside the 1200 s a first run (which compiles) is given
@@ -163,8 +168,6 @@ def profiled(enabled: bool):
     reduced trace in the yielded dict's "trace"; None when not enabled."""
     import jax
 
-    import trace_reduce
-
     out = {"trace": None}
     if not enabled:
         yield out
@@ -182,65 +185,40 @@ def profiled(enabled: bool):
         out["trace"] = trace_reduce.reduce_trace(xplane) if xplane else None
 
 
-def main(argv=None, root: Path = ROOT, require_tpu: bool = True) -> int:
-    ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
-    ap.add_argument("--workload", required=True)
-    ap.add_argument("--seed", type=int, required=True)
-    ap.add_argument("--seconds", type=float, required=True)
-    ap.add_argument("--trace", type=int, choices=(0, 1), default=0)
-    args = ap.parse_args(argv)
-    faulthandler.dump_traceback_later(WATCHDOG_S, exit=True)
-    spec = load_cell(root, args.workload)
-    cfg, traffic = spec["config"], spec["traffic"]
+class ClosedLoop:
+    """One caller that sends the next call to `linalg.decompose` when the
+    last one's factors are ready.  Set-up: the link probe of a pool in host
+    memory and the traffic's warm-up calls."""
 
-    import jax
+    def __init__(self, pool, cfg: dict, traffic: dict, run_seed: int):
+        from repro import linalg
 
-    import loadgen
-    import reference
-    import sources
-    import trace_reduce
-    import work
-    from repro import linalg
+        self.linalg, self.traffic, self.seed = linalg, traffic, run_seed
+        self.srcs = list(pool.members)
+        self.k = cfg["rank"]
+        self.link_bytes_per_s = None
+        if traffic["residency"] == "host":
+            self.link_bytes_per_s = measure_link(
+                pool.members[0][:traffic["link_probe_rows"]])
+        self.calls = 0
+        self.first = {}   # the first solve of each member, for the repeat check
+        for _ in range(traffic["warmup_calls"]):
+            member, seed = loadgen.call(traffic, run_seed, self.calls)
+            self.first.setdefault(member, solve(linalg, self.srcs[member], self.k, seed)[0])
+            self.calls += 1
 
-    loadgen.validate(traffic)
-    enable_compile_cache(root)
-    devices = jax.devices()
-    chips = spec["cell"]["chips"]
-    least_time_s = None
-    if require_tpu:
-        if devices[0].platform != "tpu":
-            raise NoChip(f"needs a TPU; jax found {devices[0].platform!r}")
-        if len(devices) < chips:
-            raise NoChip(f"the cell needs {chips} chips; jax found {len(devices)}")
-        peaks = work.peaks_for(devices[0].device_kind)
-        least_time_s = work.least_time_s(work.required_work(cfg), peaks)[0]
-    used = devices[:chips]
-    compile_log = CompileLog()
+    def window(self, seconds: float) -> tuple[float, float]:
+        import jax
 
-    # ---- set-up: the pool, the link probe, warm-up of every program --------
-    pool = sources.Pool(cfg, traffic, args.seed, used[0])
-    srcs = list(pool.members)
-    link_bytes_per_s = None
-    if traffic["residency"] == "host":
-        link_bytes_per_s = measure_link(pool.members[0][:traffic["link_probe_rows"]])
-    k = cfg["rank"]
-    calls = 0
-    first, repeat = {}, None   # the first two solves of one member
-    for _ in range(traffic["warmup_calls"]):
-        member, seed = loadgen.call(traffic, args.seed, calls)
-        first.setdefault(member, solve(linalg, srcs[member], k, seed)[0])
-        calls += 1
-    setup_s = time.perf_counter() - T_START
-
-    # ---- the window --------------------------------------------------------
-    records, failed, attempted = [], 0, 0
-    sample = loadgen.Reservoir(traffic["check_sample"], args.seed)
-    with profiled(args.trace == 1) as traced:
+        linalg, srcs, k, traffic = self.linalg, self.srcs, self.k, self.traffic
+        calls, first, repeat = self.calls, self.first, None
+        records, failed, attempted = [], 0, 0
+        sample = loadgen.Reservoir(traffic["check_sample"], self.seed)
         t0 = time.perf_counter()
         with jax.profiler.TraceAnnotation(trace_reduce.WINDOW_SPAN):
-            while time.perf_counter() - t0 < args.seconds:
+            while time.perf_counter() - t0 < seconds:
                 with jax.profiler.TraceAnnotation("next_source"):
-                    member, seed = loadgen.call(traffic, args.seed, calls)
+                    member, seed = loadgen.call(traffic, self.seed, calls)
                     calls += 1
                 attempted += 1
                 try:
@@ -258,6 +236,69 @@ def main(argv=None, root: Path = ROOT, require_tpu: bool = True) -> int:
                     else:
                         first[member] = factors
         t1 = records[-1]["t_ready"] if records else time.perf_counter()
+        self.solves, self.failed, self.attempted = records, failed, attempted
+        self.sample, self.repeat = sample.items, repeat
+        return t0, t1
+
+    def end_to_end(self, setup_s: float, window_s: float) -> dict:
+        return {
+            "solves_per_s": len(self.solves) / window_s if self.solves else None,
+            "setup_s": setup_s,
+        }
+
+    def ctx(self) -> dict:
+        return {"link_bytes_per_s": self.link_bytes_per_s}
+
+    def close(self) -> None:
+        self.srcs = None
+
+
+def main(argv=None, root: Path = ROOT, require_tpu: bool = True) -> int:
+    ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
+    ap.add_argument("--workload", required=True)
+    ap.add_argument("--seed", type=int, required=True)
+    ap.add_argument("--seconds", type=float, required=True)
+    ap.add_argument("--trace", type=int, choices=(0, 1), default=0)
+    args = ap.parse_args(argv)
+    faulthandler.dump_traceback_later(WATCHDOG_S, exit=True)
+    spec = load_cell(root, args.workload)
+    cfg, traffic = spec["config"], spec["traffic"]
+
+    import jax
+
+    import reference
+    import sources
+    import work
+
+    loadgen.validate(traffic)
+    enable_compile_cache(root)
+    devices = jax.devices()
+    chips = spec["cell"]["chips"]
+    least_time_s = None
+    if require_tpu:
+        if devices[0].platform != "tpu":
+            raise NoChip(f"needs a TPU; jax found {devices[0].platform!r}")
+        if len(devices) < chips:
+            raise NoChip(f"the cell needs {chips} chips; jax found {len(devices)}")
+        peaks = work.peaks_for(devices[0].device_kind)
+        least_time_s = work.least_time_s(work.required_work(cfg), peaks)[0]
+    used = devices[:chips]
+    compile_log = CompileLog()
+
+    # ---- set-up: the pool, then the loop's own (warm-up of every program) --
+    pool = sources.Pool(cfg, traffic, args.seed, used[0])
+    if traffic["loop"] == "open":
+        import openloop
+
+        loop = openloop.OpenLoop(pool, cfg, traffic, args.seed)
+    else:
+        loop = ClosedLoop(pool, cfg, traffic, args.seed)
+    k = cfg["rank"]
+    setup_s = time.perf_counter() - T_START
+
+    # ---- the window --------------------------------------------------------
+    with profiled(args.trace == 1) as traced:
+        t0, t1 = loop.window(args.seconds)
     trace = traced["trace"]
     window_s = t1 - t0
     compile_s = compile_log.seconds_between(t0, t1)
@@ -267,50 +308,47 @@ def main(argv=None, root: Path = ROOT, require_tpu: bool = True) -> int:
     # ---- metrics -----------------------------------------------------------
     metrics = {}
     if args.trace == 0:
-        values = {
-            "solves_per_s": len(records) / window_s if records else None,
-            "setup_s": setup_s,
-        }
+        values = loop.end_to_end(setup_s, window_s)
         for m in spec["end_to_end"]:
             if values.get(m["name"]) is not None:
                 metrics[m["name"]] = {"value": values[m["name"]], "unit": m["unit"]}
     else:
         if trace is None:
             raise RuntimeError("the traced window holds no device operation")
-        ctx = {"solves": records, "window_s": window_s, "compile_s": compile_s,
+        ctx = {"solves": loop.solves, "window_s": window_s, "compile_s": compile_s,
                "trace": trace, "least_time_s": least_time_s,
-               "link_bytes_per_s": link_bytes_per_s,
-               "pass_bytes": work.required_work(cfg)["bytes"] // (cfg["power_iters"] + 1)}
+               "pass_bytes": work.required_work(cfg)["bytes"] // (cfg["power_iters"] + 1),
+               **loop.ctx()}
         for m in spec["per_layer"]:
             value = _reader(spec["metrics_dir"], m["name"])(ctx)
             if value is not None:
                 metrics[m["name"]] = {"value": value, "unit": m["unit"]}
 
     # ---- the check, once the window has closed -----------------------------
-    del srcs
+    loop.close()
     worst = {}
     last_member, A = None, None
-    for member, factors in sorted(sample.items, key=lambda it: it[0]):
+    for member, factors in sorted(loop.sample, key=lambda it: it[0]):
         if member != last_member:
             A, last_member = pool.device_member(member), member
         for name, v in reference.judge(A, factors, pool.sigma, k).items():
             worst[name] = max(worst.get(name, v), v)
-    if repeat is not None:
+    if loop.repeat is not None:
         # two calls on one matrix with different sketch seeds: bit-identical
         # factors mean a result was kept and served again
-        worst["repeat_identical"] = reference.identical(*repeat)
+        worst["repeat_identical"] = reference.identical(*loop.repeat)
     # a number that is missing or not finite prints as null and fails
     check = {name: {"value": worst[name] if math.isfinite(worst.get(name, math.inf))
                     else None, "limit": limit}
              for name, limit in spec["limits"].items()}
-    correct = (failed == 0 and bool(sample.items)
+    correct = (loop.failed == 0 and bool(loop.sample)
                and all(c["value"] is not None and c["value"] <= c["limit"]
                        for c in check.values()))
 
     result = {
         "correct": correct,
-        "attempted": attempted,
-        "failed": failed,
+        "attempted": loop.attempted,
+        "failed": loop.failed,
         "metrics": metrics,
         "device": {"platform": devices[0].platform, "kind": devices[0].device_kind,
                    "count": len(devices), "memory_peak_bytes": memory_peak},
@@ -321,7 +359,7 @@ def main(argv=None, root: Path = ROOT, require_tpu: bool = True) -> int:
                                "idle_gaps": trace["idle_gaps"]}
     result["check"] = check
     print(f"set-up compiled for {compile_log.seconds_between(0.0, t0)!r} s", file=sys.stderr)
-    print(f"reference numbers, worst of {len(sample.items)} sampled solves: "
+    print(f"reference numbers, worst of {len(loop.sample)} sampled solves: "
           f"{worst}", file=sys.stderr)
     for name, c in check.items():
         print(f"check {name} {c['value']!r} limit {c['limit']!r}", file=sys.stderr)

@@ -1,7 +1,8 @@
 """The control: the plain reference in the program's place, run through the
 harness's own comparison under each cell's committed limits, at the cell's
-own configuration on the CPU.  With its products at one bf16 pass it must
-come out not correct; at `highest` it must pass."""
+own configuration on the CPU (an open loop's over a short window).  With
+its products at one bf16 pass it must come out not correct; at `highest`
+it must pass."""
 from __future__ import annotations
 
 import json
@@ -11,7 +12,13 @@ import pytest
 
 from test_rehearsal import BENCH, ROOT, run_cell
 
-CELLS = [w["name"] for w in json.loads((ROOT / "BENCHMARK.json").read_text())["workloads"]]
+WORKLOADS = json.loads((ROOT / "BENCHMARK.json").read_text())["workloads"]
+CELLS = [w["name"] for w in WORKLOADS]
+#: an open loop's control answers each request in the sender, at the cell's
+#: own rate: its window is short
+SECONDS = {w["name"]: 0.5 if json.loads((BENCH / "traffic" / f"{w['traffic']}.json")
+                                        .read_text())["loop"] == "open" else 3.0
+           for w in WORKLOADS}
 
 
 def checkout(tmp_path):
@@ -26,7 +33,7 @@ def checkout(tmp_path):
 def test_control_fails_and_reference_passes(tmp_path, cell, precision):
     root = checkout(tmp_path)
     patch = f"import control; control.plant({cell!r}, {precision!r}, run.ROOT)"
-    result = run_cell(root, cell, patch=patch, seconds=3.0)
+    result = run_cell(root, cell, patch=patch, seconds=SECONDS[cell])
     limits = json.loads((BENCH / "limits" / f"{cell}.json").read_text())
     assert {name: c["limit"] for name, c in result["check"].items()} == limits
     assert result["failed"] == 0

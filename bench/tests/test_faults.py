@@ -16,6 +16,13 @@ chip skipped and one fault planted under `repro.linalg.decompose`:
 - memo: every matrix's first result is kept and returned again for it,
   whatever the sketch seed (no cache may serve a call: the check holds two
   solves of one matrix to differ).
+
+The open-loop cell has the same faults planted behind
+`DecompositionService.submit` (stale, half_rows, altered, memo), and one of
+its own: misrouted, every request resolved with the factors of the pool's
+next matrix (a coalesced batch handing its slices to the wrong members).
+A request that never resolves is a failure of its own: it counts in
+`failed` and the run is not correct.
 """
 from __future__ import annotations
 
@@ -88,6 +95,86 @@ def broken(a, spec, **kw):
 linalg.decompose = broken
 """,
 }
+
+
+#: the service's submit, wrapped: `_resolve_with(inner, fn)` is a future
+#: that resolves with fn(inner's result)
+SERVICE = """
+import dataclasses
+from concurrent.futures import Future
+import jax.numpy as jnp
+from repro.serve import decomp
+_real = decomp.DecompositionService.submit
+def _resolve_with(inner, fn):
+    outer = Future()
+    inner.add_done_callback(lambda f: outer.set_result(fn(f.result())))
+    return outer
+"""
+
+SERVICE_FAULTS = {
+    "stale": SERVICE + """
+_first = []
+def broken(self, a, spec, **kw):
+    if not _first:
+        _first.append(_real(self, a, spec, **kw))
+    return _first[0]
+decomp.DecompositionService.submit = broken
+""",
+    "half_rows": SERVICE + """
+def broken(self, a, spec, **kw):
+    return _real(self, jnp.asarray(a).at[a.shape[0] // 2:].set(0), spec, **kw)
+decomp.DecompositionService.submit = broken
+""",
+    "altered": SERVICE + """
+def altered(dec):
+    U, S, Vt = dec.factors
+    return dataclasses.replace(dec, factors=(U, S * 1.01, Vt))
+def broken(self, a, spec, **kw):
+    return _resolve_with(_real(self, a, spec, **kw), altered)
+decomp.DecompositionService.submit = broken
+""",
+    "memo": SERVICE + """
+_kept = {}
+def broken(self, a, spec, **kw):
+    if id(a) not in _kept:
+        _kept[id(a)] = _real(self, a, spec, **kw)
+    return _kept[id(a)]
+decomp.DecompositionService.submit = broken
+""",
+    "misrouted": SERVICE + """
+_seen = []
+def broken(self, a, spec, **kw):
+    if not any(b is a for b in _seen):
+        _seen.append(a)
+    nxt = _seen[([id(b) for b in _seen].index(id(a)) + 1) % len(_seen)]
+    return _real(self, nxt, spec, **kw)
+decomp.DecompositionService.submit = broken
+""",
+}
+
+
+@pytest.mark.parametrize("fault", sorted(SERVICE_FAULTS))
+def test_service_fault_is_not_correct(tmp_path, fault):
+    root = make_checkout(tmp_path, "service")
+    result = run_cell(root, "tiny.service", patch=SERVICE_FAULTS[fault])
+    assert result["failed"] == 0
+    assert not result["correct"], result["check"]
+
+
+def test_request_that_never_resolves_fails(tmp_path):
+    """One window arrival's future never resolves (set-up makes 15 calls):
+    after the drain it counts in `failed`, and the run is not correct."""
+    root = make_checkout(tmp_path, "service", drain_s=1.0)
+    patch = SERVICE + """
+_calls = [0]
+def broken(self, a, spec, **kw):
+    _calls[0] += 1
+    return Future() if _calls[0] == 20 else _real(self, a, spec, **kw)
+decomp.DecompositionService.submit = broken
+"""
+    result = run_cell(root, "tiny.service", patch=patch)
+    assert result["failed"] == 1
+    assert not result["correct"]
 
 
 @pytest.mark.parametrize("fault,residency", [

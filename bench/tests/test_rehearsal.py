@@ -34,27 +34,50 @@ LIMITS = {"residual_ratio": 1.1, "u_orth_err": 1e-4, "v_orth_err": 1e-4,
           "repeat_identical": 0}
 
 
-def make_checkout(tmp: Path, residency: str, limits=None) -> Path:
-    """A copy of the benchmark plus one new cell, `tiny.<residency>`,
-    added as data files and BENCHMARK.json entries only."""
+#: the traffic of each tiny cell, by kind: a closed loop over a pool on the
+#: device or in host memory, or an open loop through the service
+TINY_TRAFFIC = {
+    "device": {"name": "closed.tiny_device", "loop": "closed", "residency": "device",
+               "pool": 3, "warmup_calls": 3, "check_sample": 4},
+    "host": {"name": "closed.tiny_host", "loop": "closed", "residency": "host",
+             "pool": 3, "warmup_calls": 3, "check_sample": 4, "link_probe_rows": 256},
+    "service": {"name": "open.tiny_service", "loop": "open", "entry": "service",
+                "residency": "device", "pool": 3, "check_sample": 4,
+                "arrivals": {"process": "poisson", "rate_per_s": 20.0}, "drain_s": 60},
+    "burst": {"name": "open.tiny_burst", "loop": "open", "entry": "service",
+              "residency": "device", "pool": 3, "check_sample": 4,
+              "arrivals": {"process": "gamma", "rate_per_s": 20.0, "cv": 2.0},
+              "drain_s": 60},
+}
+#: the committed cell whose metrics each kind of tiny cell reports, and the
+#: end-to-end metrics it adds: a bursty open loop is reported by the
+#: requests it completes as well as by its tail
+TEMPLATE = {"device": "dense.paper", "host": "host.paper", "service": "service.pca_poisson",
+            "burst": "service.pca_poisson"}
+ADDED = {"burst": ("solves_per_s",)}
+
+
+def make_checkout(tmp: Path, kind: str, limits=None, **traffic) -> Path:
+    """A copy of the benchmark plus one new cell, `tiny.<kind>`, added as
+    data files and BENCHMARK.json entries only; `traffic` overrides fields
+    of its traffic mix."""
     root = tmp / "checkout"
     shutil.copytree(BENCH, root / "bench",
                     ignore=shutil.ignore_patterns("tests", "__pycache__"))
     bench = json.loads((ROOT / "BENCHMARK.json").read_text())
-    cell = f"tiny.{residency}"
-    traffic = f"closed.tiny_{residency}"
+    cell = f"tiny.{kind}"
+    mix = dict(TINY_TRAFFIC[kind], **traffic)
     bench["configs"].append({"name": TINY_CONFIG["name"], "source": "rehearsal",
                              "file": "bench/configs/tiny_2048x256.json",
                              "reduced": [], "why": "rehearsal"})
     bench["workloads"].append({"name": cell, "config": TINY_CONFIG["name"],
-                               "traffic": traffic, "chips": 1, "why": "rehearsal"})
-    for m in bench["per_layer"]:
-        m["workloads"].append(cell)
+                               "traffic": mix["name"], "chips": 1, "why": "rehearsal"})
+    for m in bench["end_to_end"] + bench["per_layer"]:
+        if TEMPLATE[kind] in m.get("workloads", ()) or m["name"] in ADDED.get(kind, ()):
+            m["workloads"].append(cell)
     (root / "BENCHMARK.json").write_text(json.dumps(bench))
     (root / "bench/configs/tiny_2048x256.json").write_text(json.dumps(TINY_CONFIG))
-    (root / f"bench/traffic/{traffic}.json").write_text(json.dumps({
-        "name": traffic, "loop": "closed", "residency": residency, "pool": 3,
-        "warmup_calls": 3, "check_sample": 4, "link_probe_rows": 256}))
+    (root / f"bench/traffic/{mix['name']}.json").write_text(json.dumps(mix))
     (root / f"bench/limits/{cell}.json").write_text(json.dumps(limits or LIMITS))
     return root
 
@@ -82,13 +105,15 @@ def run_cell(root: Path, workload: str, trace: int = 0, seed: int = 2**31 + 7,
     return result
 
 
-@pytest.mark.parametrize("residency", ["device", "host"])
-def test_new_cell_runs_from_data_alone(tmp_path, residency):
-    root = make_checkout(tmp_path, residency)
-    result = run_cell(root, f"tiny.{residency}")
+@pytest.mark.parametrize("kind", ["device", "host", "service", "burst"])
+def test_new_cell_runs_from_data_alone(tmp_path, kind):
+    root = make_checkout(tmp_path, kind)
+    result = run_cell(root, f"tiny.{kind}")
     assert result["correct"], result
     assert result["failed"] == 0 and result["attempted"] >= 1
-    assert set(result["metrics"]) == {"solves_per_s", "setup_s"}
+    reported = {"service": {"latency_ms_p95"},
+                "burst": {"latency_ms_p95", "solves_per_s"}}.get(kind, {"solves_per_s"})
+    assert set(result["metrics"]) == reported | {"setup_s"}
     assert result["device"]["count"] == 1
     checked = result["check"]
     assert set(checked) == set(LIMITS)

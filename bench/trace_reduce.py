@@ -4,7 +4,8 @@ The trace is the `.xplane.pb` that `jax.profiler` writes.  The device
 planes are named `/device:TPU:<i>`; their `XLA Ops` line holds one event
 per operation that ran on that chip.  The benchmark puts its own host
 spans on the same clock with `jax.profiler.TraceAnnotation`: `window`
-around the measured loop, and inside it `call`, `wait` and `next_source`.
+around the measured loop, and inside it `call`, `wait` and `next_source`
+(a closed loop) or the sender's `submit` and `sleep` (an open loop).
 
 - busy: the union of the chip's op intervals inside the window;
 - idle share: 1 - busy / window, averaged over the chips;
@@ -23,7 +24,7 @@ from pathlib import Path
 DEVICE_PLANE = re.compile(r"^/device:TPU:\d+$")
 OPS_LINE = "XLA Ops"
 WINDOW_SPAN = "window"
-HOST_SPANS = ("call", "wait", "next_source")
+HOST_SPANS = ("call", "wait", "next_source", "submit", "sleep")
 TOP = 10
 
 
