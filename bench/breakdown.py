@@ -335,8 +335,6 @@ def main(argv=None) -> int:
     import jax
 
     import run
-    import sources
-    import work
 
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--workload", required=True)
@@ -345,10 +343,11 @@ def main(argv=None) -> int:
     ap.add_argument("--out", type=Path, default=run.ROOT / "chiprun_out" / "breakdown")
     args = ap.parse_args(argv)
     spec = run.load_cell(run.ROOT, args.workload)
-    cfg, traffic = spec["config"], spec["traffic"]
+    cfg, traffic, kind = spec["config"], spec["traffic"], spec["kind"]
     run.enable_compile_cache(run.ROOT)
-    device = jax.devices()[0]
-    pool = sources.Pool(cfg, traffic, args.seed, device)
+    devices = jax.devices()[:spec["cell"]["chips"]]
+    device = devices[0]
+    pool = kind.build_pool(cfg, traffic, args.seed, devices)
     if traffic["loop"] == "open":
         import openloop
 
@@ -375,7 +374,7 @@ def main(argv=None) -> int:
     delta = {n: v - before.get(n, 0) for n, v in after.items()
              if v != before.get(n, 0)}
     solves = delta.get("linalg.solves")
-    pass_bytes = work.required_work(cfg)["bytes"] // (cfg["power_iters"] + 1)
+    pass_bytes = kind.required_work(cfg)["bytes"] // (cfg["power_iters"] + 1)
     out.update(device=device.device_kind, xplane=str(xplane), counters=delta,
                attempted=loop.attempted,
                h2d_passes=(delta.get("pipeline.h2d_bytes", 0) / solves / pass_bytes

@@ -73,16 +73,16 @@ def main(argv=None) -> int:
     import loadgen
     import openloop
     import run
-    import sources
 
     spec = run.load_cell(run.ROOT, args.workload)
     cfg, traffic = spec["config"], loadgen.validate(spec["traffic"])
     if traffic["loop"] != "open":
         raise SystemExit(f"{args.workload} is not an open-loop cell")
     run.enable_compile_cache(run.ROOT)
-    device = jax.devices()[0]
+    devices = jax.devices()[:spec["cell"]["chips"]]
+    device = devices[0]
     t_setup = time.perf_counter()
-    pool = sources.Pool(cfg, traffic, args.seed, device)
+    pool = spec["kind"].build_pool(cfg, traffic, args.seed, devices)
     loop = openloop.OpenLoop(pool, cfg, traffic, args.seed)
     print(json.dumps({"device": device.device_kind,
                       "setup_s": time.perf_counter() - t_setup}), flush=True)

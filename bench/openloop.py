@@ -33,6 +33,7 @@ import jax
 import numpy as np
 
 import loadgen
+import sources
 import trace_reduce
 
 #: how often set-up submits a batch size whose group the admission window
@@ -137,14 +138,16 @@ def batch_sizes(service_cls) -> list[int]:
 
 class OpenLoop:
     """Set-up, one or more windows, and the check's sample, of one open-loop
-    cell on a pool already on the device."""
+    cell on a pool already on the device: each call submits its member as
+    `sources.program_inputs` gives it, under the configuration's spec."""
 
     def __init__(self, pool, cfg: dict, traffic: dict, run_seed: int):
         from repro import linalg
         from repro.serve import decomp
 
-        self.pool, self.traffic, self.seed = pool, traffic, run_seed
-        self.spec = linalg.Rank(cfg["rank"])
+        self.traffic, self.seed = traffic, run_seed
+        self.srcs = sources.program_inputs(linalg, pool)
+        self.spec = sources.program_spec(linalg, cfg)
         service_cls = decomp.DecompositionService
         self.svc = service_cls()
         self.calls = 0
@@ -153,7 +156,7 @@ class OpenLoop:
 
     def _submit(self, i: int):
         member, seed = loadgen.call(self.traffic, self.seed, i)
-        return self.svc.submit(self.pool.members[member], self.spec, seed=seed)
+        return self.svc.submit(self.srcs[member], self.spec, seed=seed)
 
     def _warm_up(self, size: int) -> None:
         """The next `size` calls as one batch; again, with the same calls

@@ -1,17 +1,15 @@
 """The plain reference that decides `correct`, and the control it is held to.
 
 A solve is judged by what it returned, against facts that do not depend on
-the program: every pool matrix has the exact spectrum sigma_i = 1/i^2 by
-construction, so the optimal rank-k error is known, and the factors'
-residual is computed here, row block by row block, at `highest` precision.
-The numbers (all "lower is better"):
+the program.  What those facts are belongs to the configuration's kind
+(bench/kinds/<kind>.py: for a dense matrix of known spectrum, the optimal
+error and the singular values themselves); the arithmetic every kind can
+share is here, all of it at `highest` precision:
 
-- residual_ratio: ||A - U diag(S) Vt||_F / ||A||_F over the optimal
-  sqrt(sum_{i>k} sigma_i^2 / sum_i sigma_i^2);
-- sigma_rel_err_top1, sigma_rel_err_top10: the largest relative error of
-  the leading 1 and 10 singular values against the exact spectrum;
-- u_orth_err, v_orth_err: max |U^T U - I| and max |Vt Vt^T - I|;
-- repeat_identical: whether two solves of one matrix under different sketch
+- `residual_sums`: ||A - U diag(S) Vt||_F^2 and ||A||_F^2, walking A's rows
+  in blocks (on each chip's own rows, where A is row-sharded);
+- `orth_errors`: max |U^T U - I| and max |Vt Vt^T - I|;
+- `identical`: whether two solves of one matrix under different sketch
   seeds returned bit-identical factors, which only a kept result does.
 
 `rsvd` is a straightforward randomized SVD (Gaussian sketch, q power
@@ -35,8 +33,6 @@ import numpy as np
 from sources import seed_key, seed_words
 
 PRECISIONS = ("highest", "high", "bf16")
-NUMBERS = ("residual_ratio", "sigma_rel_err_top1", "sigma_rel_err_top10",
-           "u_orth_err", "v_orth_err")
 RESIDUAL_BLOCK_ROWS = 8192
 
 
@@ -56,16 +52,9 @@ def matmul(a, b, precision: str):
     raise ValueError(f"unknown precision {precision!r}; one of {PRECISIONS}")
 
 
-def optimal_ratio(sigma: np.ndarray, k: int) -> float:
-    """||A - A_k||_F / ||A||_F from the exact spectrum."""
-    sq = np.asarray(sigma, np.float64) ** 2
-    return float(np.sqrt(sq[k:].sum() / sq.sum()))
-
-
-@jax.jit
-def _residual_sums(A, U, svt):
-    """[||A - U S Vt||_F^2, ||A||_F^2], walking A's rows in blocks so that
-    no m x n temporary exists."""
+def _walk_rows(A, U, svt):
+    """[||A - U S Vt||_F^2, ||A||_F^2] of the rows at hand, in blocks so
+    that no rows x n temporary exists."""
     rows, n = A.shape
     b = RESIDUAL_BLOCK_ROWS if rows % RESIDUAL_BLOCK_ROWS == 0 else rows
 
@@ -76,6 +65,45 @@ def _residual_sums(A, U, svt):
         return acc + jnp.stack([jnp.sum(R * R), jnp.sum(Ab * Ab)])
 
     return jax.lax.fori_loop(0, rows // b, body, jnp.zeros(2, jnp.float32))
+
+
+_residual_sums = jax.jit(_walk_rows)
+
+
+@functools.lru_cache(maxsize=None)
+def _residual_sums_rows(mesh, axis: str):
+    """`_walk_rows` on each chip's own rows of a row-sharded A and U, the
+    two sums added over the chips."""
+    rows = jax.sharding.PartitionSpec(axis, None)
+
+    def local(A, U, svt):
+        return jax.lax.psum(_walk_rows(A, U, svt), axis)
+
+    # check_vma off: the block walk's carry starts replicated and becomes
+    # per-chip, which the psum makes replicated again
+    return jax.jit(jax.shard_map(local, mesh=mesh,
+                                 in_specs=(rows, rows, jax.sharding.PartitionSpec()),
+                                 out_specs=jax.sharding.PartitionSpec(), check_vma=False))
+
+
+def residual_sums(A, factors, sharding=None) -> np.ndarray:
+    """[||A - U diag(S) Vt||_F^2, ||A||_F^2] in float64, at `highest`;
+    `sharding` is (mesh, axis) where A is row-sharded, and U is then laid
+    over the same rows."""
+    U, S, Vt = factors
+    S = jnp.asarray(S, jnp.float32)
+    svt = S[:, None] * jnp.asarray(Vt, jnp.float32)
+    U = jnp.asarray(U, jnp.float32)
+    if sharding is None:
+        sums = _residual_sums(A, U, svt)
+    else:
+        mesh, axis = sharding
+        U = jax.device_put(U, jax.sharding.NamedSharding(
+            mesh, jax.sharding.PartitionSpec(axis, None)))
+        svt = jax.device_put(svt, jax.sharding.NamedSharding(
+            mesh, jax.sharding.PartitionSpec()))
+        sums = _residual_sums_rows(mesh, axis)(A, U, svt)
+    return np.asarray(jax.device_get(sums), np.float64)
 
 
 def _orth_err(X):
@@ -89,28 +117,9 @@ def _orth_errors(U, Vt):
     return jnp.stack([_orth_err(U), _orth_err(Vt.T)])
 
 
-def judge(A, factors, sigma: np.ndarray, k: int) -> dict:
-    """The numbers of one solve.  A shape or a value that is not finite
-    reads as inf."""
-    U, S, Vt = factors
-    m, n = A.shape
-    if U.shape != (m, k) or S.shape != (k,) or Vt.shape != (k, n):
-        return {name: float("inf") for name in NUMBERS}
-    S = jnp.asarray(S, jnp.float32)
-    svt = S[:, None] * jnp.asarray(Vt, jnp.float32)
-    sums = np.asarray(jax.device_get(
-        _residual_sums(A, jnp.asarray(U, jnp.float32), svt)), np.float64)
-    orth = np.asarray(jax.device_get(_orth_errors(U, Vt)), np.float64)
-    s = np.asarray(jax.device_get(S), np.float64)
-    rel = np.abs(s - sigma[:k]) / sigma[:k]
-    out = {
-        "residual_ratio": float(np.sqrt(sums[0] / sums[1]) / optimal_ratio(sigma, k)),
-        "sigma_rel_err_top1": float(rel[0]),
-        "sigma_rel_err_top10": float(rel[:10].max()),
-        "u_orth_err": float(orth[0]),
-        "v_orth_err": float(orth[1]),
-    }
-    return {name: (v if np.isfinite(v) else float("inf")) for name, v in out.items()}
+def orth_errors(U, Vt) -> np.ndarray:
+    """[max |U^T U - I|, max |Vt Vt^T - I|] in float64."""
+    return np.asarray(jax.device_get(_orth_errors(U, Vt)), np.float64)
 
 
 def identical(factors_a, factors_b) -> float:
@@ -139,9 +148,22 @@ def rsvd(A, seed_words, *, k: int, oversample: int, power_iters: int,
     return matmul(Q, Ub[:, :k], precision), S[:k], Vt[:k]
 
 
+def _meeting(a, factors, eps: float) -> tuple:
+    """The fewest leading components of a full-rank `factors` whose
+    posterior residual meets the relative tolerance eps."""
+    U, S, Vt = factors
+    norm_sq = float(jnp.sum(jnp.square(a)))
+    tail = norm_sq - np.cumsum(np.asarray(jax.device_get(S), np.float64) ** 2)
+    met = np.nonzero(tail <= eps ** 2 * norm_sq)[0]
+    r = int(met[0]) + 1 if met.size else len(tail)
+    return U[:, :r], S[:r], Vt[:r]
+
+
 def decompose(precision: str, oversample: int, power_iters: int):
-    """A stand-in for `repro.linalg.decompose(a, Rank(k), seed=...)` that
-    runs `rsvd` at `precision`: what the control plants in a run."""
+    """A stand-in for `repro.linalg.decompose(a, spec, seed=...)` that runs
+    `rsvd` at `precision`: what the control plants in a run.  Under a
+    `Tolerance` it factors at full rank and keeps the fewest leading
+    components whose residual, ||A||_F^2 less their energy, meets eps."""
     import dataclasses
 
     @dataclasses.dataclass(frozen=True)
@@ -149,8 +171,13 @@ def decompose(precision: str, oversample: int, power_iters: int):
         factors: tuple
 
     def call(a, spec, seed=0, **_):
-        factors = rsvd(jnp.asarray(a), seed_words(seed), k=spec.k, oversample=oversample,
+        a = jnp.asarray(getattr(a, "array", a))   # a ShardedOp's array
+        eps = getattr(spec, "eps", None)
+        k = min(a.shape) if eps is not None else spec.k
+        factors = rsvd(a, seed_words(seed), k=k, oversample=oversample,
                        power_iters=power_iters, precision=precision)
+        if eps is not None:
+            factors = _meeting(a, factors, eps)
         return Decomposition(factors)
 
     return call

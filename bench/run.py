@@ -5,18 +5,22 @@
 
 A cell is an entry of `workloads` in BENCHMARK.json: a configuration
 (bench/configs/, the matrix and the solve) under a traffic mix
-(bench/traffic/<name>.json, read by loadgen.py).  The run builds the
-cell's pool of matrices on the device from --seed, warms up every program
-the window runs (set-up), then measures for --seconds: a closed loop calls
-`repro.linalg.decompose`, each call ended by `block_until_ready` on its
-factors (ClosedLoop, below); an open loop submits to
-`DecompositionService` on a fixed schedule and drains (openloop.py).
+(bench/traffic/<name>.json, read by loadgen.py).  The configuration's kind
+(bench/kinds/<kind>.py, found by name, "dense_spectrum" where it names
+none) builds the cell's pool of matrices on the cell's devices from
+--seed, judges a solve and prices one; sources.py reads the spec each call
+passes and the placement over the chips.  The run builds the pool, warms
+up every program the window runs (set-up), then measures for --seconds: a
+closed loop calls `repro.linalg.decompose`, each call ended by
+`block_until_ready` on its factors (ClosedLoop, below); an open loop
+submits to `DecompositionService` on a fixed schedule and drains
+(openloop.py).
 
 With --trace 0 the metrics are the cell's end-to-end metrics; with
 --trace 1 the window runs under the profiler and the metrics are the
 cell's per-layer metrics, each read by bench/metrics/<name>.py.  After the
-window, a sample of the window's solves drawn from the seed is judged
-against the plain reference (reference.py) and the limits in
+window, a sample of the window's solves drawn from the seed is judged by
+the kind against the plain reference (reference.py) and the limits in
 bench/limits/<cell>.json; each compared number is printed beside its limit,
 as the last lines on stderr and under "check", the last key of the result.
 
@@ -47,6 +51,7 @@ sys.path.insert(0, str(BENCH_DIR))
 sys.path.insert(1, str(ROOT / "src"))
 
 import loadgen  # noqa: E402
+import sources  # noqa: E402
 import trace_reduce  # noqa: E402
 
 #: past this many seconds the run dumps every thread's stack and exits
@@ -72,7 +77,9 @@ def _selected(entries: list, cell: str) -> list:
 
 def load_cell(root: Path, name: str) -> dict:
     """Everything the named cell needs, found by name: its entry, its
-    configuration file, its traffic file, its limits and its metrics."""
+    configuration file and kind, its traffic file, its limits and its
+    metrics.  A configuration whose spec or placement the cell cannot run
+    is an error here, before set-up."""
     bench = json.loads((root / "BENCHMARK.json").read_text())
     cells = {w["name"]: w for w in bench["workloads"]}
     if name not in cells:
@@ -80,10 +87,14 @@ def load_cell(root: Path, name: str) -> dict:
     cell = cells[name]
     configs = {c["name"]: c for c in bench["configs"]}
     bench_dir = root / bench["paths"][0]
+    cfg = json.loads((root / configs[cell["config"]]["file"]).read_text())
     traffic = json.loads((bench_dir / "traffic" / f"{cell['traffic']}.json").read_text())
+    sources.spec(cfg)
+    sources.placement(cfg, traffic, cell["chips"])
     return {
         "cell": cell,
-        "config": json.loads((root / configs[cell["config"]]["file"]).read_text()),
+        "config": cfg,
+        "kind": _module(bench_dir / "kinds", cfg.get("kind", "dense_spectrum"), "kind"),
         "traffic": traffic,
         "limits": json.loads((bench_dir / "limits" / f"{name}.json").read_text()),
         "end_to_end": _selected(bench["end_to_end"], name),
@@ -92,12 +103,20 @@ def load_cell(root: Path, name: str) -> dict:
     }
 
 
-def _reader(metrics_dir: Path, name: str):
+def _module(directory: Path, name: str, role: str):
+    """The module `<directory>/<name>.py`, loaded under a name of its own."""
+    path = directory / f"{name}.py"
+    if not path.is_file():
+        raise SystemExit(f"no {role} {name!r}: {path} is missing")
     spec = importlib.util.spec_from_file_location(
-        f"bench_metric_{name.replace('.', '_')}", metrics_dir / f"{name}.py")
+        f"bench_{role}_{name.replace('.', '_')}", path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
-    return mod.read
+    return mod
+
+
+def _reader(metrics_dir: Path, name: str):
+    return _module(metrics_dir, name, "metric").read
 
 
 def enable_compile_cache(root: Path) -> None:
@@ -148,13 +167,13 @@ def measure_link(host_panel) -> float:
     return host_panel.nbytes / times[len(times) // 2]
 
 
-def solve(linalg, source, k: int, seed: int):
+def solve(linalg, source, spec, seed: int):
     """One call, ended by block_until_ready on all its factors."""
     import jax
 
     t_call = time.perf_counter()
     with jax.profiler.TraceAnnotation("call"):
-        dec = linalg.decompose(source, linalg.Rank(k), seed=seed)
+        dec = linalg.decompose(source, spec, seed=seed)
     t_return = time.perf_counter()
     with jax.profiler.TraceAnnotation("wait"):
         factors = jax.block_until_ready(tuple(dec.factors))
@@ -194,8 +213,8 @@ class ClosedLoop:
         from repro import linalg
 
         self.linalg, self.traffic, self.seed = linalg, traffic, run_seed
-        self.srcs = list(pool.members)
-        self.k = cfg["rank"]
+        self.srcs = sources.program_inputs(linalg, pool)
+        self.spec = sources.program_spec(linalg, cfg)
         self.link_bytes_per_s = None
         if traffic["residency"] == "host":
             self.link_bytes_per_s = measure_link(
@@ -204,13 +223,13 @@ class ClosedLoop:
         self.first = {}   # the first solve of each member, for the repeat check
         for _ in range(traffic["warmup_calls"]):
             member, seed = loadgen.call(traffic, run_seed, self.calls)
-            self.first.setdefault(member, solve(linalg, self.srcs[member], self.k, seed)[0])
+            self.first.setdefault(member, solve(linalg, self.srcs[member], self.spec, seed)[0])
             self.calls += 1
 
     def window(self, seconds: float) -> tuple[float, float]:
         import jax
 
-        linalg, srcs, k, traffic = self.linalg, self.srcs, self.k, self.traffic
+        linalg, srcs, spec, traffic = self.linalg, self.srcs, self.spec, self.traffic
         calls, first, repeat = self.calls, self.first, None
         records, failed, attempted = [], 0, 0
         sample = loadgen.Reservoir(traffic["check_sample"], self.seed)
@@ -222,7 +241,7 @@ class ClosedLoop:
                     calls += 1
                 attempted += 1
                 try:
-                    factors, rec = solve(linalg, srcs[member], k, seed)
+                    factors, rec = solve(linalg, srcs[member], spec, seed)
                 except Exception:  # a solve that raises counts in `failed`
                     failed += 1
                     if failed == 1:
@@ -262,12 +281,11 @@ def main(argv=None, root: Path = ROOT, require_tpu: bool = True) -> int:
     args = ap.parse_args(argv)
     faulthandler.dump_traceback_later(WATCHDOG_S, exit=True)
     spec = load_cell(root, args.workload)
-    cfg, traffic = spec["config"], spec["traffic"]
+    cfg, traffic, kind = spec["config"], spec["traffic"], spec["kind"]
 
     import jax
 
     import reference
-    import sources
     import work
 
     loadgen.validate(traffic)
@@ -281,19 +299,18 @@ def main(argv=None, root: Path = ROOT, require_tpu: bool = True) -> int:
         if len(devices) < chips:
             raise NoChip(f"the cell needs {chips} chips; jax found {len(devices)}")
         peaks = work.peaks_for(devices[0].device_kind)
-        least_time_s = work.least_time_s(work.required_work(cfg), peaks)[0]
+        least_time_s = work.least_time_s(kind.required_work(cfg), peaks, chips)[0]
     used = devices[:chips]
     compile_log = CompileLog()
 
     # ---- set-up: the pool, then the loop's own (warm-up of every program) --
-    pool = sources.Pool(cfg, traffic, args.seed, used[0])
+    pool = kind.build_pool(cfg, traffic, args.seed, used)
     if traffic["loop"] == "open":
         import openloop
 
         loop = openloop.OpenLoop(pool, cfg, traffic, args.seed)
     else:
         loop = ClosedLoop(pool, cfg, traffic, args.seed)
-    k = cfg["rank"]
     setup_s = time.perf_counter() - T_START
 
     # ---- the window --------------------------------------------------------
@@ -317,7 +334,7 @@ def main(argv=None, root: Path = ROOT, require_tpu: bool = True) -> int:
             raise RuntimeError("the traced window holds no device operation")
         ctx = {"solves": loop.solves, "window_s": window_s, "compile_s": compile_s,
                "trace": trace, "least_time_s": least_time_s,
-               "pass_bytes": work.required_work(cfg)["bytes"] // (cfg["power_iters"] + 1),
+               "pass_bytes": kind.required_work(cfg)["bytes"] // (cfg["power_iters"] + 1),
                **loop.ctx()}
         for m in spec["per_layer"]:
             value = _reader(spec["metrics_dir"], m["name"])(ctx)
@@ -327,11 +344,9 @@ def main(argv=None, root: Path = ROOT, require_tpu: bool = True) -> int:
     # ---- the check, once the window has closed -----------------------------
     loop.close()
     worst = {}
-    last_member, A = None, None
+    # by member, so that a kind can keep one member on the device at a time
     for member, factors in sorted(loop.sample, key=lambda it: it[0]):
-        if member != last_member:
-            A, last_member = pool.device_member(member), member
-        for name, v in reference.judge(A, factors, pool.sigma, k).items():
+        for name, v in kind.judge(pool, member, factors, cfg).items():
             worst[name] = max(worst.get(name, v), v)
     if loop.repeat is not None:
         # two calls on one matrix with different sketch seeds: bit-identical

@@ -1,24 +1,43 @@
-"""The matrices a cell factors, built on the device from the run's seed.
+"""What a configuration says about the program's inputs, beside the
+matrices themselves: the run seed's words, the spec each call passes, and
+the placement of the pool over the cell's chips.
 
-Every pool member is A = U diag(sigma) V^T with sigma_i = 1/i^2, U and V
-the Q factors of Gaussian matrices.  Member 0 is the base; member j > 0 is
-the base with its rows permuted and sign-flipped, which keeps the singular
-values exactly.  The whole pool comes out of one jitted call.
+The matrices are a kind's (bench/kinds/<kind>.py, found by the
+configuration's "kind", "dense_spectrum" where it gives none).  A kind
+module has three functions:
 
-Everything here is the benchmark's own: it imports nothing of the program.
+- build_pool(cfg, traffic, seed, devices): the pool, on the cell's
+  devices; `pool.members[j]` is the matrix that call j factors, and
+  `pool.sharding`, where the pool has one, the (mesh, axis) its members are
+  row-sharded on; the rest is whatever the kind's judge needs;
+- judge(pool, member, factors, cfg): the numbers of one solve, which the
+  cell's limits file names;
+- required_work(cfg): {"flops", "bytes"} of one solve, from the
+  configuration's sizes alone.
+
+The configuration's optional fields, each absent meaning what it always
+did:
+
+- "kind": the module that builds, judges and prices the pool;
+- "spec": absent, `Rank(rank)`; {"kind": "tolerance", "eps": e},
+  `Tolerance(eps=e)`, the program's Frobenius relative-error target;
+- "placement": absent, every member on the cell's one chip; "rows", every
+  member row-sharded over the cell's chips on a 1-D mesh, passed to the
+  program as `ShardedOp(A, mesh, "rows")`.
+
+Everything here is the benchmark's own: it imports nothing of the program,
+and is handed `repro.linalg` where it builds the program's arguments.
 """
 from __future__ import annotations
 
-import functools
+import numbers
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 
-
-def spectrum(n: int) -> np.ndarray:
-    """sigma_i = 1/i^2, i = 1..n, in float64."""
-    return 1.0 / np.arange(1, n + 1, dtype=np.float64) ** 2
+PLACEMENTS = ("rows",)
+#: the mesh axis of a row-sharded pool
+ROWS_AXIS = "rows"
 
 
 def seed_key(seed):
@@ -33,49 +52,56 @@ def seed_words(seed: int) -> np.ndarray:
     return np.array([seed % 2 ** 32, seed // 2 ** 32], np.uint32)
 
 
-def _orthonormal(key, rows: int, cols: int):
-    Q, R = jnp.linalg.qr(jax.random.normal(key, (rows, cols), jnp.float32))
-    return Q * jnp.sign(jnp.diagonal(R))[None, :]
+def spec(cfg: dict) -> dict:
+    """The configuration's spec as plain data: {"kind": "rank", "k": rank}
+    where it states none, or {"kind": "tolerance", "eps": eps}."""
+    s = cfg.get("spec")
+    if s is None:
+        return {"kind": "rank", "k": cfg["rank"]}
+    eps = s.get("eps") if isinstance(s, dict) else None
+    if (not isinstance(s, dict) or s.get("kind") != "tolerance"
+            or isinstance(eps, bool) or not isinstance(eps, numbers.Real)
+            or not 0 < eps < 1):
+        raise ValueError(f"configuration {cfg['name']}: spec must be absent or "
+                         '{"kind": "tolerance", "eps": e} with 0 < e < 1')
+    return {"kind": "tolerance", "eps": float(eps)}
 
 
-@functools.partial(jax.jit, static_argnames=("m", "n", "pool"))
-def build(seed, *, m: int, n: int, pool: int):
-    """The pool's `pool` matrices, m x n f32, from the seed's words."""
-    key = seed_key(seed)
-    r = min(m, n)
-    U = _orthonormal(jax.random.fold_in(key, 0), m, r)
-    V = _orthonormal(jax.random.fold_in(key, 1), n, r)
-    sig = 1.0 / jnp.arange(1, r + 1, dtype=jnp.float32) ** 2
-    base = jnp.matmul(U * sig[None, :], V.T, precision=jax.lax.Precision.HIGHEST)
-    members = [base]
-    for j in range(1, pool):
-        k_perm, k_sign = jax.random.split(jax.random.fold_in(key, 2 + j))
-        perm = jax.random.permutation(k_perm, m)
-        signs = jax.random.rademacher(k_sign, (m,), jnp.float32)
-        members.append(base[perm] * signs[:, None])
-    return tuple(members)
+def program_spec(linalg, cfg: dict):
+    """The spec object every call of the cell passes to the program."""
+    s = spec(cfg)
+    if s["kind"] == "rank":
+        return linalg.Rank(s["k"])
+    return linalg.Tolerance(eps=s["eps"])
 
 
-class Pool:
-    """The cell's matrices: `members[j]` is what call j passes to the
-    program (a device array or a numpy array), `device_member(j)` the same
-    matrix on the device for the reference, and `sigma` the exact spectrum
-    every member shares."""
+def placement(cfg: dict, traffic: dict, chips: int):
+    """The configuration's placement over the cell's chips, checked before
+    set-up: a cell on more than one chip has to say how its matrices lie
+    over them, and is never run on one chip alone."""
+    where = cfg.get("placement")
+    if where is not None and where not in PLACEMENTS:
+        raise ValueError(f"configuration {cfg['name']}: placement must be absent "
+                         f"or one of {PLACEMENTS}, not {where!r}")
+    if chips > 1 and where is None:
+        raise ValueError(f"configuration {cfg['name']} names no placement, and its "
+                         f"cell asks for {chips} chips: it would run on one")
+    if where == "rows" and traffic["residency"] != "device":
+        raise ValueError(f"configuration {cfg['name']}: a row-sharded pool lives "
+                         f"on the device, not in {traffic['residency']!r} memory")
+    return where
 
-    def __init__(self, cfg: dict, traffic: dict, seed: int, device):
-        if cfg["dtype"] != "float32" or cfg["spectrum"] != "inverse_square":
-            raise ValueError(f"unsupported configuration {cfg['name']}")
-        self.device = device
-        self.sigma = spectrum(min(cfg["m"], cfg["n"]))
-        with jax.default_device(device):
-            members = build(seed_words(seed), m=cfg["m"], n=cfg["n"],
-                            pool=traffic["pool"])
-        if traffic["residency"] == "host":
-            members = [np.asarray(A) for A in jax.device_get(members)]
-        self.members = list(jax.block_until_ready(members))
 
-    def device_member(self, j: int):
-        A = self.members[j]
-        if isinstance(A, np.ndarray):
-            return jax.device_put(A, self.device)
-        return A
+def row_mesh(devices):
+    """The 1-D mesh a row-sharded pool lies on."""
+    return jax.sharding.Mesh(np.array(devices), (ROWS_AXIS,))
+
+
+def program_inputs(linalg, pool) -> list:
+    """What each call passes to the program for each pool member: the
+    member as it is, or, where the pool has a `sharding`, the member
+    wrapped in `ShardedOp` as a user of a sharded matrix does."""
+    if getattr(pool, "sharding", None) is None:
+        return list(pool.members)
+    mesh, axis = pool.sharding
+    return [linalg.ShardedOp(A, mesh, axis) for A in pool.members]

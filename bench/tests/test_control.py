@@ -10,7 +10,8 @@ import shutil
 
 import pytest
 
-from test_rehearsal import BENCH, ROOT, run_cell
+from test_rehearsal import (BENCH, ROOT, TOLERANCE, TOLERANCE_LIMITS, make_checkout,
+                             run_cell)
 
 WORKLOADS = json.loads((ROOT / "BENCHMARK.json").read_text())["workloads"]
 CELLS = [w["name"] for w in WORKLOADS]
@@ -36,5 +37,18 @@ def test_control_fails_and_reference_passes(tmp_path, cell, precision):
     result = run_cell(root, cell, patch=patch, seconds=SECONDS[cell])
     limits = json.loads((BENCH / "limits" / f"{cell}.json").read_text())
     assert {name: c["limit"] for name, c in result["check"].items()} == limits
+    assert result["failed"] == 0
+    assert result["correct"] == (precision == "highest"), result["check"]
+
+
+@pytest.mark.parametrize("precision", ["bf16", "highest"])
+def test_control_under_a_tolerance_spec(tmp_path, precision):
+    """The control answers a tolerance spec too: it factors at full rank
+    and keeps the fewest leading components whose residual meets eps."""
+    root = make_checkout(tmp_path, "device", limits=TOLERANCE_LIMITS,
+                         config={"spec": TOLERANCE})
+    patch = ("import control, pathlib; "
+             f"control.plant('tiny.device', {precision!r}, pathlib.Path({str(root)!r}))")
+    result = run_cell(root, "tiny.device", patch=patch, seconds=3.0)
     assert result["failed"] == 0
     assert result["correct"] == (precision == "highest"), result["check"]

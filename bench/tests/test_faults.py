@@ -23,12 +23,17 @@ its own: misrouted, every request resolved with the factors of the pool's
 next matrix (a coalesced batch handing its slices to the wrong members).
 A request that never resolves is a failure of its own: it counts in
 `failed` and the run is not correct.
+
+A cell under a tolerance spec has faults of its own: short_rank, half of
+the returned components dropped (too small a rank); loose, the solve run
+to ten times the tolerance (a residual over it); overshoot, to a tenth of
+it (far more rank than the tolerance needs); and altered, as above.
 """
 from __future__ import annotations
 
 import pytest
 
-from test_rehearsal import make_checkout, run_cell
+from test_rehearsal import TOLERANCE, TOLERANCE_LIMITS, make_checkout, run_cell
 
 WRAP = """
 import jax, jax.numpy as jnp, numpy as np
@@ -94,6 +99,29 @@ def broken(a, spec, **kw):
     return _kept[id(a)]
 linalg.decompose = broken
 """,
+}
+
+TOLERANCE_FAULTS = {
+    "short_rank": WRAP + """
+import dataclasses
+def broken(a, spec, **kw):
+    dec = _real(a, spec, **kw)
+    U, S, Vt = dec.factors
+    r = max(1, S.shape[0] // 2)
+    return dataclasses.replace(dec, factors=(U[:, :r], S[:r], Vt[:r]))
+linalg.decompose = broken
+""",
+    "loose": WRAP + """
+def broken(a, spec, **kw):
+    return _real(a, linalg.Tolerance(eps=spec.eps * 10), **kw)
+linalg.decompose = broken
+""",
+    "overshoot": WRAP + """
+def broken(a, spec, **kw):
+    return _real(a, linalg.Tolerance(eps=spec.eps / 10), **kw)
+linalg.decompose = broken
+""",
+    "altered": FAULTS["altered"],
 }
 
 
@@ -185,5 +213,14 @@ decomp.DecompositionService.submit = broken
 def test_fault_is_not_correct(tmp_path, fault, residency):
     root = make_checkout(tmp_path, residency)
     result = run_cell(root, f"tiny.{residency}", patch=FAULTS[fault])
+    assert result["failed"] == 0
+    assert not result["correct"], result["check"]
+
+
+@pytest.mark.parametrize("fault", sorted(TOLERANCE_FAULTS))
+def test_tolerance_fault_is_not_correct(tmp_path, fault):
+    root = make_checkout(tmp_path, "device", limits=TOLERANCE_LIMITS,
+                         config={"spec": TOLERANCE})
+    result = run_cell(root, "tiny.device", patch=TOLERANCE_FAULTS[fault])
     assert result["failed"] == 0
     assert not result["correct"], result["check"]
